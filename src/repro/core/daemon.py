@@ -101,6 +101,7 @@ class TracingDaemon:
         self._c_bytes = self.telemetry.counter("daemon.bytes_logged")
         self._c_events = self.telemetry.counter("daemon.events_emitted")
         self._c_spill_errors = self.telemetry.counter("daemon.spill_errors")
+        self._c_sink_errors = self.telemetry.counter("daemon.sink_errors")
         self._g_heartbeat = self.telemetry.gauge("daemon.heartbeat_age_s")
         self._g_queue = self.telemetry.gauge("daemon.queue_depth")
         self._g_rate = self.telemetry.gauge("daemon.events_per_s")
@@ -226,6 +227,10 @@ class TracingDaemon:
     def spill_errors(self) -> int:
         return self._c_spill_errors.value
 
+    @property
+    def sink_errors(self) -> int:
+        return self._c_sink_errors.value
+
     def _emit(self, ev: TraceEvent):
         self.buffer.append(ev)
         self._c_events.inc()
@@ -319,11 +324,13 @@ class TracingDaemon:
             return
         if self.cfg.reconstruct:
             reconstruct_stacks(events)
+        # a failing sink must not kill the daemon thread (that would end
+        # the hang heartbeat too); each failure is counted instead
         for sink in self._sinks:
             try:
                 sink(events)
             except Exception:
-                pass
+                self._c_sink_errors.inc()
         if self._batch_sinks or self._spill is not None:
             from repro.core.columnar import EventBatch
             batch = EventBatch.from_events(events)
@@ -331,7 +338,7 @@ class TracingDaemon:
                 try:
                     sink(batch)
                 except Exception:
-                    pass
+                    self._c_sink_errors.inc()
             if self._spill is not None:
                 # one codec segment (or JSONL line run) per drain; guarded
                 # like the sinks — a spill error (disk full, unserializable

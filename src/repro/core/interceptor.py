@@ -9,10 +9,6 @@ implemented in C (no bytecode — e.g. ``gc.collect``) fall back to a wrapper
 installed by the daemon at attach time (still zero backend modification),
 and GC pauses themselves are additionally captured via ``gc.callbacks``.
 
-On Python < 3.12 ``sys.monitoring`` does not exist; EVERY registered API
-then takes the wrapper path, which preserves the plug-and-play contract
-(install at attach, restore at detach, daemon threads never traced).
-
 Easy-to-play interface (paper): environment variable
     FLARE_TRACED_PYTHON_API="jax@block_until_ready,gc@collect,mod.sub@fn"
 """
@@ -29,7 +25,6 @@ from typing import Callable, Optional
 
 ENV_VAR = "FLARE_TRACED_PYTHON_API"
 _TOOL_NAME = "flare"
-_HAS_MONITORING = hasattr(sys, "monitoring")   # PEP 669, Python >= 3.12
 
 
 def parse_api_spec(spec: str) -> list[tuple[str, str]]:
@@ -90,13 +85,13 @@ class PyApiInterceptor:
             return False
         code = getattr(f, "__code__", None)
         name = f"{module}@{func}"
-        if code is not None and _HAS_MONITORING:
+        if code is not None:
             self._traced[code] = _Traced(module, func, code=code)
             if self._tool_id is not None:
                 self._enable_local(code)
         else:
-            # C-implemented API — or an interpreter without sys.monitoring:
-            # wrapper fallback (installed at attach, not backend-edited)
+            # C-implemented API: wrapper fallback (installed at attach,
+            # not backend-edited)
             info = _Traced(module, func, original=f)
 
             def wrapper(*a, __flare_name=name, __orig=f, **kw):
@@ -115,22 +110,21 @@ class PyApiInterceptor:
 
     # ------------------------------------------------------------------ #
     def install(self):
-        if _HAS_MONITORING:
-            mon = sys.monitoring
-            for tid in range(6):
-                try:
-                    mon.use_tool_id(tid, _TOOL_NAME)
-                    self._tool_id = tid
-                    break
-                except ValueError:
-                    continue
-            if self._tool_id is None:
-                raise RuntimeError("no free sys.monitoring tool id")
-            E = mon.events
-            mon.register_callback(self._tool_id, E.PY_START, self._py_start)
-            mon.register_callback(self._tool_id, E.PY_RETURN, self._py_return)
-            for code in self._traced:
-                self._enable_local(code)
+        mon = sys.monitoring
+        for tid in range(6):
+            try:
+                mon.use_tool_id(tid, _TOOL_NAME)
+                self._tool_id = tid
+                break
+            except ValueError:
+                continue
+        if self._tool_id is None:
+            raise RuntimeError("no free sys.monitoring tool id")
+        E = mon.events
+        mon.register_callback(self._tool_id, E.PY_START, self._py_start)
+        mon.register_callback(self._tool_id, E.PY_RETURN, self._py_return)
+        for code in self._traced:
+            self._enable_local(code)
         if not self._gc_cb_installed:
             gc.callbacks.append(self._gc_cb)
             self._gc_cb_installed = True
@@ -141,7 +135,7 @@ class PyApiInterceptor:
             self._tool_id, code, E.PY_START | E.PY_RETURN)
 
     def uninstall(self):
-        if _HAS_MONITORING and self._tool_id is not None:
+        if self._tool_id is not None:
             for code in self._traced:
                 sys.monitoring.set_local_events(self._tool_id, code, 0)
             sys.monitoring.free_tool_id(self._tool_id)

@@ -2,14 +2,16 @@
 
 Kernels (each: kernel.py = pl.pallas_call + BlockSpec, ops.py = jit'd
 wrapper, ref.py = pure-jnp oracle):
-  flash_attention  — blocked online-softmax causal GQA attention
   padded_matmul    — Case-2: MXU-alignment padding inside the tile
   ssd_scan         — Mamba2 chunked state-space scan
   fused_norm       — residual+RMSNorm fusion (Table-5 minority kernels)
   ring_reduce      — ring-combine step with progress export (intra-kernel
                      inspecting seam)
 
-``interpret_default()`` is True off-TPU so kernels validate on CPU.
+No model path calls them yet; ``tests/test_tpu_compile.py`` holds each to
+the v5e compiler at real widths.  ``interpret_default()`` is True on the
+CPU backend, where the tests validate them, and any backend other than
+CPU or TPU is an error rather than a silent switch to the interpreter.
 Every ops.py entry point self-registers with an attached FLARE daemon —
 this is the paper's explicit "C++ interface" registration (§4.1).
 """
@@ -22,7 +24,12 @@ import jax
 
 
 def interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"these Pallas kernels run on the TPU, or interpreted on the "
+            f"CPU; JAX's backend is {backend!r}")
+    return backend == "cpu"
 
 
 def traced_op(name: str, kind: str = "compute",

@@ -9,18 +9,21 @@ via async copies; under a hang its frozen values feed
 repro.core.inspecting.diagnose_ring directly.
 
 Grid: (chunk_elems // block,) — progress[i] = i+1 after block i combines.
+The progress vector sits whole in SMEM (one scalar store per block), so
+its block never has to match the vector-memory tiling.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _combine_kernel(acc_ref, in_ref, o_ref, prog_ref):
     i = pl.program_id(0)
     o_ref[...] = acc_ref[...] + in_ref[...]
-    prog_ref[0] = i + 1  # SASS step-counter analogue, host-readable
+    prog_ref[i] = i + 1  # SASS step-counter analogue, host-readable
 
 
 def ring_combine_step(acc, incoming, *, block=1024, interpret=False):
@@ -35,7 +38,7 @@ def ring_combine_step(acc, incoming, *, block=1024, interpret=False):
         in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
                   pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[jax.ShapeDtypeStruct((C,), acc.dtype),
                    jax.ShapeDtypeStruct((n_blocks,), jnp.int32)],
         interpret=interpret,

@@ -6,6 +6,12 @@ grid steps for a fixed (b, h), reset at chunk 0.  Within a chunk the
 quadratic intra-term runs on the MXU; the state update is two small
 matmuls.  This is the TPU-native shape of the SSD algorithm: HBM traffic
 is O(L·(P+N)) while compute stays MXU-dense.
+
+The wrapper moves heads ahead of the sequence so that every VMEM block
+ends in a (chunk, lanes) tile, and hands ``dt`` in twice — as a column
+[chunk, 1] and as a row [1, chunk] — so the kernel forms the inclusive
+cumulative decay in both orientations with masked reductions instead of
+1-D scans or transposes.  The per-head ``A`` sits whole in SMEM.
 """
 from __future__ import annotations
 
@@ -17,36 +23,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
-                chunk):
+def _ssd_kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref,
+                state_ref, *, chunk):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _reset():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[...].astype(jnp.float32)          # [Q, P]
-    dt = dt_ref[...].astype(jnp.float32)        # [Q]
-    A = a_ref[0].astype(jnp.float32)            # scalar (this head)
-    Bm = b_ref[...].astype(jnp.float32)         # [Q, N]
-    Cm = c_ref[...].astype(jnp.float32)         # [Q, N]
+    A = a_ref[pl.program_id(1)]                  # scalar (this head)
+    x = x_ref[...].astype(jnp.float32)           # [Q, P]
+    dt_c = dtc_ref[...].astype(jnp.float32)      # [Q, 1]
+    dt_r = dtr_ref[...].astype(jnp.float32)      # [1, Q]
+    Bm = b_ref[...].astype(jnp.float32)          # [Q, N]
+    Cm = c_ref[...].astype(jnp.float32)          # [Q, N]
 
-    dA = dt * A                                  # [Q], <= 0
-    cum = jnp.cumsum(dA)                         # inclusive decay
-    # ---- intra-chunk (quadratic) ---------------------------------------- #
-    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # [Q, Q]
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(cum[:, None] - cum[None, :])
-    scores = jnp.where(ti >= si, cb * decay, 0.0) * dt[None, :]
-    y = jax.lax.dot(scores, x)                   # [Q, P]
+    causal = ti >= si
+    # inclusive decay cum[t] = sum_{s<=t} dt[s]*A (<= 0), in both layouts
+    cum_c = jnp.sum(jnp.where(causal, dt_r * A, 0.0), axis=1,
+                    keepdims=True)               # [Q, 1]
+    cum_r = jnp.sum(jnp.where(ti <= si, dt_c * A, 0.0), axis=0,
+                    keepdims=True)               # [1, Q]
+    total = jnp.sum(dt_r * A, axis=1, keepdims=True)   # [1, 1]
+    # ---- intra-chunk (quadratic) ---------------------------------------- #
+    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # [Q, Q]
+    decay = jnp.exp(jnp.where(causal, cum_c - cum_r, -jnp.inf))
+    y = jax.lax.dot(cb * decay * dt_r, x)        # [Q, P]
     # ---- inter-chunk (state) -------------------------------------------- #
     S = state_ref[...]                           # [N, P]
-    y += jnp.exp(cum)[:, None] * jax.lax.dot(Cm, S)
-    w = jnp.exp(cum[-1] - cum) * dt              # [Q]
-    S_new = jnp.exp(cum[-1]) * S + jax.lax.dot_general(
-        Bm, w[:, None] * x, (((0,), (0,)), ((), ())))  # [N, P]
-    state_ref[...] = S_new
+    y += jnp.exp(cum_c) * jax.lax.dot(Cm, S)
+    w = jnp.exp(total - cum_c) * dt_c            # [Q, 1]
+    state_ref[...] = jnp.exp(total) * S + jax.lax.dot_general(
+        Bm, w * x, (((0,), (0,)), ((), ())))     # [N, P]
     y_ref[...] = y.astype(y_ref.dtype)
 
 
@@ -56,21 +66,24 @@ def ssd_scan_fwd(x, dt, A, Bm, Cm, *, chunk=128, interpret=False):
     N = Bm.shape[-1]
     chunk = min(chunk, L)
     assert L % chunk == 0
-    grid = (B, H, L // chunk)
+    dt_h = dt.transpose(0, 2, 1)                 # [B, H, L]
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H, L // chunk),
         in_specs=[
-            pl.BlockSpec((None, chunk, None, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((None, chunk, None), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((None, None, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((None, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((None, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((None, chunk, None, P),
-                               lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_specs=pl.BlockSpec((None, None, chunk, P),
+                               lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+    )(x.transpose(0, 2, 1, 3), dt_h[..., None], dt_h[:, :, None, :],
+      A.astype(jnp.float32), Bm, Cm)
+    return y.transpose(0, 2, 1, 3)

@@ -286,8 +286,6 @@ def analyze(compiled, lowered, info, chips: int) -> dict:
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     scan_aware = analyze_hlo(hlo)  # multiplies through while-loop trip counts
     flops = float(scan_aware["flops"])            # per-device

@@ -10,10 +10,37 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
+
+import jax
 
 from repro.configs import get_config, get_reduced, list_archs
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.train import RunConfig, Trainer
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at one fixed directory and
+    return it.  JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; only when
+    that is unset is the cache pointed at ``<repo>/.jax_cache``.  The path
+    is part of the cache key, so it never comes from a temp name, a pid
+    or the time.  Call it from an entry point before the first compile,
+    never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_summary() -> dict:
+    """The device JAX will run on, as every result should name it."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def main():
@@ -37,6 +64,8 @@ def main():
     ap.add_argument("--flare-log", default=None)
     args = ap.parse_args()
 
+    use_compile_cache()
+    print(json.dumps({"device": device_summary()}), flush=True)
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     run = RunConfig(
         model=cfg, global_batch=args.batch, seq_len=args.seq,

@@ -15,8 +15,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.compat import shard_map
-
 from repro.models.layers import Constrain, apply_rope, normal_init, null_constrain
 
 NEG_INF = -1e30
@@ -241,8 +239,7 @@ def chunked_attention(q, k, v, causal=True, q_offset=0,
 
     Residuals are only (q, k, v, o, lse) — scores are recomputed per chunk
     in the VJP, so train-time memory is O(S) not O(S^2) (the XLA analogue
-    of the flash-attention backward; see kernels/flash_attention for the
-    Pallas TPU version).  q_offset may be a traced scalar (context
+    of the flash-attention backward).  q_offset may be a traced scalar (context
     parallelism passes the per-shard row offset)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
@@ -354,7 +351,7 @@ def context_parallel_attention(q, k, v, mesh, *, causal=True, q_offset=0,
                                  q_chunk=min(q_chunk, s_loc),
                                  kv_chunk=kv_chunk)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, model_axis), P(bspec), P(bspec)),
         out_specs=P(bspec, model_axis),

@@ -21,8 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.compat import shard_map
-
 from repro.configs import ModelConfig
 from repro.models.layers import Constrain, normal_init, null_constrain
 
@@ -134,7 +132,7 @@ def moe_apply(params, x, cfg: ModelConfig, mesh=None, model_axis="model",
         y = expert_ff_local(xf, ei, wi, wg, wu, wo, shard * E_loc, C)
         return jax.lax.psum(y, model_axis)
 
-    y = shard_map(
+    y = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(dp_axes), P(dp_axes), P(dp_axes),
                   P(model_axis), P(model_axis), P(model_axis)),

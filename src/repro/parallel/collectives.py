@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 
 def _ring_perm(n: int, reverse: bool = False):
     if reverse:
@@ -111,7 +109,7 @@ def ring_all_reduce(x, mesh: Mesh, axis: str = "model",
         return ring_all_reduce_local(xs, axis, n, with_progress)
 
     other = tuple(a for a in mesh.axis_names if a != axis)
-    res, prog = shard_map(
+    res, prog = jax.shard_map(
         body, mesh=mesh,
         in_specs=P(),
         out_specs=(P(), P(axis)),

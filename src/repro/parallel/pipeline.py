@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
                    mesh: Mesh, axis: str = "stage"):
@@ -66,7 +64,7 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
         outs = outs * (stage == S - 1)
         return jax.lax.psum(outs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

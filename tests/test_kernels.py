@@ -4,8 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.fused_norm.ops import fused_residual_rmsnorm
 from repro.kernels.fused_norm.ref import fused_ref
 from repro.kernels.padded_matmul.ops import padded_matmul
@@ -16,21 +14,6 @@ from repro.kernels.ssd_scan.ref import ssd_ref
 
 TOLS = {jnp.float32: dict(rtol=3e-4, atol=3e-4),
         jnp.bfloat16: dict(rtol=5e-2, atol=5e-2)}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("shape", [(1, 256, 4, 2, 64), (2, 384, 6, 3, 32),
-                                   (1, 128, 2, 1, 128)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_sweep(rng, shape, dtype, causal):
-    B, S, H, KV, hd = shape
-    q = jnp.asarray(rng.standard_normal((B, S, H, hd)), dtype)
-    k = jnp.asarray(rng.standard_normal((B, S, KV, hd)), dtype)
-    v = jnp.asarray(rng.standard_normal((B, S, KV, hd)), dtype)
-    o = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
-    r = attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), **TOLS[dtype])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -84,3 +67,11 @@ def test_ring_combine(rng, C, block):
     out, prog = ring_combine(a, b, block=block)
     np.testing.assert_allclose(out, a + b, rtol=1e-6)
     np.testing.assert_array_equal(prog, np.arange(1, C // block + 1))
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    from repro.kernels import interpret_default
+    assert interpret_default() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        interpret_default()

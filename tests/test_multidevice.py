@@ -13,7 +13,6 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.mesh import make_test_mesh
     from repro.parallel.collectives import (ring_all_reduce,
                                             compressed_psum_local)
-    from repro.parallel.compat import shard_map
     from repro.parallel.pipeline import pipeline_apply
 
     mesh = make_test_mesh(data=2, model=4)
@@ -33,7 +32,7 @@ SCRIPT = textwrap.dedent("""
         out, err = compressed_psum_local(v, "model", None)
         return out, err
     xs = jnp.linspace(-2, 2, 64).reshape(8, 8)
-    out, err = jax.jit(shard_map(
+    out, err = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(), out_specs=(P(), P("model")),
         check_vma=False))(xs)
     np.testing.assert_allclose(np.asarray(out), 4 * np.asarray(xs),

@@ -80,6 +80,32 @@ def test_daemon_traces_env_api_gc_and_kernels(tmp_path):
         del os.environ["FLARE_TRACED_PYTHON_API"]
 
 
+def test_daemon_counts_failing_sinks():
+    """A sink that raises is counted in the telemetry registry; the other
+    sinks still receive every event and the daemon thread lives on."""
+    d = TracingDaemon(DaemonConfig(rank=0, drain_interval=0.01,
+                                   hang_timeout=1e9))
+    got = []
+
+    def broken(_):
+        raise OSError("sink down")
+
+    d.add_sink(broken)
+    d.add_batch_sink(broken)
+    d.add_sink(got.extend)
+    d.attach()
+    for step in range(3):
+        d.step_begin(step)
+        d.step_end(tokens=8)
+        time.sleep(0.05)
+    d.detach()
+    assert [e.name for e in got if e.kind == EventKind.STEP] == [
+        "step_0", "step_1", "step_2"]
+    # every drain fails both broken sinks, so the count is even and >= 2
+    assert d.sink_errors >= 2 and d.sink_errors % 2 == 0
+    assert d.telemetry.value("daemon.sink_errors") == d.sink_errors
+
+
 def test_daemon_hang_heartbeat():
     d = TracingDaemon(DaemonConfig(rank=0, hang_timeout=0.05,
                                    drain_interval=0.01))
