@@ -1,7 +1,7 @@
 """Benchmark driver — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (see benchmarks/_util.emit).
-  Fig 8   -> overhead        Fig 9  -> logsize
+  Fig 9   -> logsize
   Fig 10  -> hang            Fig 11 -> issue_dist
   Table 4 -> regression      Fig 12 -> case2_matmul
   Table 5 -> vminority       §Roofline -> roofline (reads dryrun_out/)
@@ -25,10 +25,9 @@ import traceback
 
 def main() -> None:
     from benchmarks import (archive, case2_matmul, fleet, hang, ingest,
-                            issue_dist, live, logsize, overhead, regression,
-                            roofline, scenarios, storage, vminority)
+                            issue_dist, live, logsize, regression, roofline,
+                            scenarios, storage, vminority)
     sections = [
-        ("fig8_overhead", overhead.main),
         ("fig9_logsize", logsize.main),
         ("fig10_hang", hang.main),
         ("fig11_issue_dist", issue_dist.main),

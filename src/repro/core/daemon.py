@@ -1,8 +1,9 @@
 """Per-process tracing daemon (paper §4): timing manager + background thread.
 
 Responsibilities (mirroring Fig 4):
-  * collect spans from the Python interceptor, the dataloader seam, GC,
-    registered kernel entry points, and step boundaries;
+  * collect spans from the Python interceptor, the training loop's phases
+    (``span``, each also a ``jax.profiler`` annotation on the profiler's
+    clock), GC, the op library's kernel entry points, and step boundaries;
   * time asynchronous device work without blocking the training thread —
     completion probing happens on the daemon thread against shadow futures
     (the CUDA-event analogue; see DESIGN.md §2);
@@ -14,8 +15,15 @@ Responsibilities (mirroring Fig 4):
     engine and/or a JSONL file.
 
 Kernel registration is the explicit "C++ interface" of the paper: the op
-library (repro.kernels.*, repro.parallel.collectives) self-registers when a
-daemon is attached; backends are never patched.
+library's entry points (``repro.kernels.traced_op``) report to the attached
+daemon; backends are never patched.
+
+Flare's own cost on the training thread is counted where it is spent:
+``span``/``record_span``, ``step_begin``, ``step_end``, ``set_stack`` and
+the interceptor's callbacks add their bookkeeping time (never the user
+code a span times) to the step's count, which ``step_end`` puts on the
+STEP event as ``flare_self_ns`` and adds to the ``daemon.self_ns``
+counter.
 """
 from __future__ import annotations
 
@@ -32,6 +40,17 @@ from repro.core.stack import reconstruct_stacks
 from repro.core.telemetry import TelemetryRegistry
 
 _GLOBAL_DAEMON: Optional["TracingDaemon"] = None
+_ANNOTATION = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that the
+    daemon's other users never import JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 @dataclass
@@ -102,6 +121,8 @@ class TracingDaemon:
         self._c_events = self.telemetry.counter("daemon.events_emitted")
         self._c_spill_errors = self.telemetry.counter("daemon.spill_errors")
         self._c_sink_errors = self.telemetry.counter("daemon.sink_errors")
+        self._c_self_ns = self.telemetry.counter("daemon.self_ns")
+        self._self_ns = 0   # this step's bookkeeping so far, in ns
         self._g_heartbeat = self.telemetry.gauge("daemon.heartbeat_age_s")
         self._g_queue = self.telemetry.gauge("daemon.queue_depth")
         self._g_rate = self.telemetry.gauge("daemon.events_per_s")
@@ -236,59 +257,60 @@ class TracingDaemon:
         self._c_events.inc()
         self._last_completion = time.perf_counter()
 
+    @property
+    def self_ns(self) -> int:
+        return self._c_self_ns.value
+
     def _on_api_span(self, name: str, t0: float, t1: float):
+        t = time.perf_counter_ns()
         self._emit(TraceEvent(EventKind.PY_API, name, self.cfg.rank,
                               t0, t0, t1, step=self._step))
+        self._self_ns += time.perf_counter_ns() - t
 
     def _on_gc(self, name: str, t0: float, t1: float):
+        t = time.perf_counter_ns()
         self._emit(TraceEvent(EventKind.GC, name, self.cfg.rank,
                               t0, t0, t1, step=self._step))
+        self._self_ns += time.perf_counter_ns() - t
 
     def record_span(self, kind: EventKind, name: str, t0: float, t1: float,
                     **meta):
+        t = time.perf_counter_ns()
         self._emit(TraceEvent(kind, name, self.cfg.rank, t0, t0, t1,
                               step=self._step, meta=meta))
+        self._self_ns += time.perf_counter_ns() - t
+
+    def span(self, kind: EventKind, name: str, **meta) -> "Span":
+        """``with daemon.span(kind, name):`` times the block as a Flare
+        span of this step, as ``record_span`` would, inside a
+        ``jax.profiler.TraceAnnotation`` of the same name, so the block
+        also lands on the profiler's host plane."""
+        return Span(self, kind, name, meta, _trace_annotation())
 
     def step_begin(self, step: int):
+        t = time.perf_counter_ns()
         self._step = step
-        self._step_t0 = time.perf_counter()
+        self._step_t0 = t * 1e-9
         self._in_step = True
+        self._self_ns += time.perf_counter_ns() - t
 
     def step_end(self, **meta):
-        t1 = time.perf_counter()
+        """Emits the STEP event with the step's Flare self time; the time
+        this call takes after that opens the next step's count."""
+        t = time.perf_counter_ns()
+        n = meta["flare_self_ns"] = self._self_ns
+        self._c_self_ns.inc(n)
         self._emit(TraceEvent(EventKind.STEP, f"step_{self._step}",
                               self.cfg.rank, self._step_t0, self._step_t0,
-                              t1, step=self._step, meta=meta))
+                              t * 1e-9, step=self._step, meta=meta))
         self._in_step = False
+        self._self_ns = time.perf_counter_ns() - t
 
     def set_stack(self, stack: list[str]):
         """Training thread publishes its logical call stack (hang analysis)."""
+        t = time.perf_counter_ns()
         self._last_stack = list(stack)
-
-    # ------------------------------------------------------------------ #
-    # kernel registration — the explicit infra-team interface
-    # ------------------------------------------------------------------ #
-    def register_kernel(self, name: str, kind: EventKind,
-                        meta_fn: Optional[Callable[..., dict]] = None):
-        """Decorator: wraps an op-library entry point.
-
-        Issue timestamp is taken at dispatch.  Completion is probed on the
-        daemon thread via a shadow `block_until_ready` on (a sample of) the
-        returned arrays — the training thread is never blocked (Fig 4).
-        """
-        def deco(fn):
-            def wrapped(*args, **kwargs):
-                if not self._attached:
-                    return fn(*args, **kwargs)
-                issue = time.perf_counter()
-                out = fn(*args, **kwargs)
-                meta = meta_fn(*args, **kwargs) if meta_fn else {}
-                self._pending.put((name, kind, issue, self._step, out, meta))
-                return out
-            wrapped.__name__ = getattr(fn, "__name__", name)
-            wrapped.__wrapped__ = fn
-            return wrapped
-        return deco
+        self._self_ns += time.perf_counter_ns() - t
 
     # ------------------------------------------------------------------ #
     # background thread: timing manager + heartbeat + streaming
@@ -383,6 +405,39 @@ class TracingDaemon:
                 except Exception:
                     pass
             self._last_completion = now  # rate-limit repeat reports
+
+
+class Span:
+    """A block timed by ``TracingDaemon.span``.  ``t0`` and ``t1`` are its
+    start and end on ``time.perf_counter``'s clock; the daemon's self
+    time takes the rest of ``__enter__`` and ``__exit__``."""
+
+    __slots__ = ("_daemon", "_kind", "_name", "_meta", "_created",
+                 "_annotation", "t0", "t1")
+
+    def __init__(self, daemon: TracingDaemon, kind: EventKind, name: str,
+                 meta: dict, annotation):
+        self._created = time.perf_counter_ns()
+        self._daemon, self._kind, self._name = daemon, kind, name
+        self._meta = meta
+        self._annotation = annotation(name)
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        t0 = time.perf_counter_ns()
+        self.t0 = t0 * 1e-9
+        self._daemon._self_ns += t0 - self._created
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter_ns()
+        self.t1 = t1 * 1e-9
+        self._annotation.__exit__(*exc)
+        d = self._daemon
+        d._emit(TraceEvent(self._kind, self._name, d.cfg.rank, self.t0,
+                           self.t0, self.t1, step=d._step, meta=self._meta))
+        d._self_ns += time.perf_counter_ns() - t1
+        return False
 
 
 # --------------------------------------------------------------------------- #

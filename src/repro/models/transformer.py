@@ -18,6 +18,8 @@ from repro.configs import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import moe as moe_lib
+from repro.runtime.train import (ATTENTION_SCOPE, EMBED_SCOPE, HEAD_SCOPE,
+                                 MLP_SCOPE)
 
 
 def _stack_init(fn, rng, n, *args):
@@ -112,61 +114,75 @@ class TransformerLM:
     def _self_block(self, p, x, positions, cache=None, pos=None):
         """Pre-norm block. Returns (x, new_kv or (k,v))."""
         cfg = self.cfg
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        if cache is None:
-            q, k, v = attn_lib.project_qkv(
-                p["attn"], h, positions=positions, rope_theta=cfg.rope_theta,
-                constrain=self.constrain)
-            if self.attn_impl == "cp" and self.mesh is not None:
-                o = attn_lib.context_parallel_attention(
-                    q, k, v, self.mesh, causal=True,
-                    q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
+        with jax.named_scope(ATTENTION_SCOPE):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            if cache is None:
+                q, k, v = attn_lib.project_qkv(
+                    p["attn"], h, positions=positions,
+                    rope_theta=cfg.rope_theta, constrain=self.constrain)
+                if self.attn_impl == "cp" and self.mesh is not None:
+                    o = attn_lib.context_parallel_attention(
+                        q, k, v, self.mesh, causal=True,
+                        q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
+                else:
+                    o = attn_lib.attention(
+                        q, k, v, causal=True, impl=self.attn_impl,
+                        fold_depth=self.fold_depth, q_chunk=self.q_chunk,
+                        kv_chunk=self.kv_chunk)
+                new_kv = (k, v)
             else:
-                o = attn_lib.attention(
-                    q, k, v, causal=True, impl=self.attn_impl,
-                    fold_depth=self.fold_depth, q_chunk=self.q_chunk,
-                    kv_chunk=self.kv_chunk)
-            new_kv = (k, v)
-        else:
-            k_cache, v_cache = cache
-            q, k, v = attn_lib.project_qkv(
-                p["attn"], h, positions=positions, rope_theta=cfg.rope_theta,
-                constrain=self.constrain)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, pos, 1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, pos, 1)
-            o = attn_lib.decode_attention(q, k_cache, v_cache, pos)
-            new_kv = (k_cache, v_cache)
-        x = x + attn_lib.project_out(p["attn"], o, self.constrain)
-        x = self.constrain(x, ("batch", "seq", "embed"))
+                k_cache, v_cache = cache
+                q, k, v = attn_lib.project_qkv(
+                    p["attn"], h, positions=positions,
+                    rope_theta=cfg.rope_theta, constrain=self.constrain)
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    k_cache, k, pos, 1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    v_cache, v, pos, 1)
+                o = attn_lib.decode_attention(q, k_cache, v_cache, pos)
+                new_kv = (k_cache, v_cache)
+            x = x + attn_lib.project_out(p["attn"], o, self.constrain)
+            x = self.constrain(x, ("batch", "seq", "embed"))
 
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        aux = jnp.zeros((), jnp.float32)
-        if self.is_moe:
-            y, aux = moe_lib.moe_apply(
-                p["moe"], h, cfg, mesh=self.mesh, constrain=self.constrain)
-            if cfg.moe_dense_residual:
-                y = y + L.mlp_apply(p["mlp"], h, self.constrain)
-        else:
-            y = L.mlp_apply(p["mlp"], h, self.constrain)
-        x = x + y
-        return self.constrain(x, ("batch", "seq", "embed")), new_kv, aux
+        with jax.named_scope(MLP_SCOPE):
+            h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+            aux = jnp.zeros((), jnp.float32)
+            if self.is_moe:
+                y, aux = moe_lib.moe_apply(
+                    p["moe"], h, cfg, mesh=self.mesh, constrain=self.constrain)
+                if cfg.moe_dense_residual:
+                    y = y + L.mlp_apply(p["mlp"], h, self.constrain)
+            else:
+                y = L.mlp_apply(p["mlp"], h, self.constrain)
+            x = x + y
+            return self.constrain(x, ("batch", "seq", "embed")), new_kv, aux
 
     def _cross_block(self, p, x, vis_kv, cache=None):
         """Gated cross-attention block (vision). vis_kv [B,Tv,D_model]."""
         cfg = self.cfg
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        q, k, v = attn_lib.project_qkv(p["attn"], h, kv_x=vis_kv,
-                                       constrain=self.constrain)
-        if cache is not None:  # decode: reuse cached cross K/V
-            k, v = cache
-        o = attn_lib.attention(q, k, v, causal=False, impl="direct"
-                               if q.shape[1] * k.shape[1] <= 1 << 22 else "chunked")
-        gate = jnp.tanh(p["attn"]["gate"].astype(x.dtype))
-        x = x + gate * attn_lib.project_out(p["attn"], o, self.constrain)
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        gate2 = jnp.tanh(p["gate_mlp"].astype(x.dtype))
-        x = x + gate2 * L.mlp_apply(p["mlp"], h, self.constrain)
+        with jax.named_scope(ATTENTION_SCOPE):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            q, k, v = attn_lib.project_qkv(p["attn"], h, kv_x=vis_kv,
+                                           constrain=self.constrain)
+            if cache is not None:  # decode: reuse cached cross K/V
+                k, v = cache
+            o = attn_lib.attention(
+                q, k, v, causal=False, impl="direct"
+                if q.shape[1] * k.shape[1] <= 1 << 22 else "chunked")
+            gate = jnp.tanh(p["attn"]["gate"].astype(x.dtype))
+            x = x + gate * attn_lib.project_out(p["attn"], o, self.constrain)
+        with jax.named_scope(MLP_SCOPE):
+            h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+            gate2 = jnp.tanh(p["gate_mlp"].astype(x.dtype))
+            x = x + gate2 * L.mlp_apply(p["mlp"], h, self.constrain)
         return x, (k, v)
+
+    def _head(self, params, x):
+        """Final norm and the tied or untied head: x -> logits."""
+        x = L.rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
+        if self.cfg.tie_embeddings:
+            return L.tied_head_apply(params["embed"], x)
+        return L.head_apply(params["head"], x)
 
     def _maybe_remat(self, fn):
         if self.remat == "full":
@@ -185,8 +201,9 @@ class TransformerLM:
         cfg = self.cfg
         cd = self.policy.compute_dtype
         B, S = tokens.shape
-        x = L.embed_apply(params["embed"], tokens, cd)
-        x = self.constrain(x, ("batch", "seq", "embed"))
+        with jax.named_scope(EMBED_SCOPE):
+            x = L.embed_apply(params["embed"], tokens, cd)
+            x = self.constrain(x, ("batch", "seq", "embed"))
         positions = jnp.arange(S)[None, :] + q_offset
 
         vis = None
@@ -222,12 +239,9 @@ class TransformerLM:
             aux_total = jnp.sum(auxs)
             kv_out = {"self": kvs}
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = L.tied_head_apply(params["embed"], x)
-        else:
-            logits = L.head_apply(params["head"], x)
-        logits = self.constrain(logits, ("batch", "seq", "vocab"))
+        with jax.named_scope(HEAD_SCOPE):
+            logits = self._head(params, x)
+            logits = self.constrain(logits, ("batch", "seq", "vocab"))
         if collect_kv:
             return logits, kv_out, aux_total
         return logits, aux_total
@@ -235,7 +249,8 @@ class TransformerLM:
     def loss(self, params, batch, vision_embeds=None):
         logits, aux = self.apply(params, batch["tokens"],
                                  vision_embeds=vision_embeds)
-        ce = L.cross_entropy(logits, batch["labels"])
+        with jax.named_scope(HEAD_SCOPE):
+            ce = L.cross_entropy(logits, batch["labels"])
         loss = ce + 0.01 * aux if self.is_moe else ce
         return loss, {"ce": ce, "aux": aux}
 
@@ -288,7 +303,8 @@ class TransformerLM:
         """token [B,1]; pos: scalar int32 index of the new token."""
         cfg = self.cfg
         cd = self.policy.compute_dtype
-        x = L.embed_apply(params["embed"], token, cd)
+        with jax.named_scope(EMBED_SCOPE):
+            x = L.embed_apply(params["embed"], token, cd)
         positions = jnp.full((token.shape[0], 1), pos, jnp.int32)
 
         if self.n_cross:
@@ -320,9 +336,6 @@ class TransformerLM:
                                                  cache["k"], cache["v"]))
             new_cache = dict(cache, k=kn, v=vn)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = L.tied_head_apply(params["embed"], x)
-        else:
-            logits = L.head_apply(params["head"], x)
+        with jax.named_scope(HEAD_SCOPE):
+            logits = self._head(params, x)
         return logits[:, 0], new_cache

@@ -4,10 +4,17 @@
 AdamW with compressed state, LR schedule).  ``Trainer`` is the driver: it
 owns the dataloader, attaches the FLARE daemon, emits step/dataloader
 events, checkpoints, and exposes fault hooks for the supervisor.
+
+The step's device work carries the named scopes below in each HLO op's
+``op_name``, and each host phase of ``Trainer.train`` is a Flare span that
+is also a profiler annotation (``TracingDaemon.span``):
+``dataloader.next_batch``, ``train_step.h2d`` (the batch's copies and the
+fault hook), ``train_step.dispatch``, ``train_step.sync`` (the loss
+fetch) and ``train_step.record`` (history, step end, checkpoint).
 """
 from __future__ import annotations
 
-import os
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -17,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ModelConfig
+from repro.core.events import EventKind
 from repro.data import DataConfig, ShardedLoader
 from repro.models.layers import Policy
 from repro.models.registry import build_model
@@ -50,6 +58,20 @@ class RunConfig:
 
     def policy(self) -> Policy:
         return Policy(jnp.dtype(self.param_dtype), jnp.dtype(self.compute_dtype))
+
+
+# ``jax.named_scope`` names of the step's layers.  Scopes only set each HLO
+# op's ``op_name`` metadata (``jvp(attention)``, ``transpose(jvp(mlp))``):
+# the compiled program is otherwise the same.
+EMBED_SCOPE = "embed"          # the token lookup
+ATTENTION_SCOPE = "attention"  # ln1 through the output projection's residual
+MLP_SCOPE = "mlp"              # ln2 through the MLP or MoE and its residual
+HEAD_SCOPE = "head"            # final norm, head and cross-entropy
+OPTIMIZER_SCOPE = "optimizer"  # adamw_update whole, with the clip
+STEP_SCOPES = (EMBED_SCOPE, ATTENTION_SCOPE, MLP_SCOPE, HEAD_SCOPE,
+               OPTIMIZER_SCOPE)
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def make_train_step(model, cfg: RunConfig, mesh=None):
@@ -100,8 +122,9 @@ def make_train_step(model, cfg: RunConfig, mesh=None):
             (grads, loss), _ = jax.lax.scan(micro, (g0, 0.0), mbs)
             grads = jax.tree.map(lambda g: g / M, grads)
             loss = loss / M
-        params, opt_state, om = adamw_update(
-            grads, opt_state, params, opt_cfg, lr)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            params, opt_state, om = adamw_update(
+                grads, opt_state, params, opt_cfg, lr)
         metrics = {"loss": loss, "lr": lr, **om}
         return params, opt_state, metrics
 
@@ -164,60 +187,60 @@ class Trainer:
                 rank=0, backend=f"{cfg.model.family}-train",
                 log_path=cfg.flare_log, hang_timeout=300.0))
             self.daemon.attach()
+        daemon = self.daemon
+        span = daemon.span if daemon else lambda *a, **k: _NO_SPAN
         loader = self._loader()
         if cfg.data_prefetch:
             loader.start()
         params, opt_state, start = self.restore_or_init()
         vis = self._vision_stub()
         tokens_per_step = cfg.global_batch * cfg.seq_len
+        step_flops = 6.0 * cfg.model.active_param_count() * tokens_per_step
         try:
             for step in range(start, steps):
-                if self.daemon:
-                    self.daemon.step_begin(step)
-                    self.daemon.set_stack(["Trainer.train", "next_batch"])
+                if daemon:
+                    daemon.step_begin(step)
+                    daemon.set_stack(["Trainer.train", "next_batch"])
                 t0 = time.perf_counter()
-                batch = loader.next_batch()
-                t_data = time.perf_counter()
-                if self.daemon:
-                    from repro.core.events import EventKind
-                    self.daemon.record_span(
-                        EventKind.DATALOADER, "dataloader.next_batch",
-                        t0, t_data, tokens=tokens_per_step)
-                    self.daemon.set_stack(["Trainer.train", "train_step"])
-                jb = {"tokens": jnp.asarray(batch["tokens"]),
-                      "labels": jnp.asarray(batch["labels"])}
-                if vis is not None:
-                    jb["vision_embeds"] = vis
-                if self.fault_hook:
-                    self.fault_hook(step)
-                t_dispatch = time.perf_counter()
-                params, opt_state, metrics = self.step_fn(
-                    params, opt_state, jb, jnp.int32(step))
-                loss = float(metrics["loss"])  # sync point
-                t_done = time.perf_counter()
-                if self.daemon:
-                    from repro.core.events import EventKind
-                    # whole-step device occupancy (the jitted step is one
-                    # fused XLA program on this backend)
-                    self.daemon.record_span(
-                        EventKind.KERNEL_COMPUTE, "train_step_exec",
-                        t_dispatch, t_done,
-                        flops=6.0 * cfg.model.active_param_count()
-                        * tokens_per_step)
-                    self.daemon.step_end(tokens=tokens_per_step, loss=loss)
-                rec = {"step": step, "loss": loss,
-                       "lr": float(metrics["lr"]),
-                       "grad_norm": float(metrics["grad_norm"]),
-                       "step_time_s": time.perf_counter() - t0,
-                       "tokens_per_s": tokens_per_step
-                       / max(time.perf_counter() - t0, 1e-9)}
-                self.history.append(rec)
-                if self.ckpt and (step + 1) % cfg.checkpoint_every == 0:
-                    self.ckpt.save(step, {"params": params, "opt": opt_state},
-                                   {"loss": loss})
+                with span(EventKind.DATALOADER, "dataloader.next_batch",
+                          tokens=tokens_per_step):
+                    batch = loader.next_batch()
+                with span(EventKind.PY_API, "train_step.h2d"):
+                    if daemon:
+                        daemon.set_stack(["Trainer.train", "train_step"])
+                    jb = {"tokens": jnp.asarray(batch["tokens"]),
+                          "labels": jnp.asarray(batch["labels"])}
+                    if vis is not None:
+                        jb["vision_embeds"] = vis
+                    if self.fault_hook:
+                        self.fault_hook(step)
+                with span(EventKind.PY_API, "train_step.dispatch") as dispatch:
+                    params, opt_state, metrics = self.step_fn(
+                        params, opt_state, jb, jnp.int32(step))
+                with span(EventKind.PY_API, "train_step.sync") as sync:
+                    loss = float(metrics["loss"])
+                with span(EventKind.PY_API, "train_step.record"):
+                    if daemon:
+                        # whole-step device occupancy (the jitted step is
+                        # one fused XLA program on this backend)
+                        daemon.record_span(
+                            EventKind.KERNEL_COMPUTE, "train_step_exec",
+                            dispatch.t0, sync.t1, flops=step_flops)
+                        daemon.step_end(tokens=tokens_per_step, loss=loss)
+                    rec = {"step": step, "loss": loss,
+                           "lr": float(metrics["lr"]),
+                           "grad_norm": float(metrics["grad_norm"]),
+                           "step_time_s": time.perf_counter() - t0,
+                           "tokens_per_s": tokens_per_step
+                           / max(time.perf_counter() - t0, 1e-9)}
+                    self.history.append(rec)
+                    if self.ckpt and (step + 1) % cfg.checkpoint_every == 0:
+                        self.ckpt.save(step,
+                                       {"params": params, "opt": opt_state},
+                                       {"loss": loss})
         finally:
             loader.stop()
-            if self.daemon:
-                self.daemon.detach()
+            if daemon:
+                daemon.detach()
         self.final_state = (params, opt_state)
         return self.history

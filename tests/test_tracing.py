@@ -10,6 +10,7 @@ from repro.core.daemon import DaemonConfig, TracingDaemon
 from repro.core.events import (EventKind, EventRingBuffer, TraceEvent,
                                load_jsonl)
 from repro.core.interceptor import parse_api_spec
+from repro.kernels import traced_op
 
 
 def test_parse_api_spec():
@@ -52,8 +53,8 @@ def test_daemon_traces_env_api_gc_and_kernels(tmp_path):
         json.dumps([1, 2, 3])
         gc.collect()
 
-        @d.register_kernel("k1", EventKind.KERNEL_COMPUTE,
-                           lambda x: {"flops": 10.0})
+        # the op library's entry points report to the attached daemon
+        @traced_op("k1", "compute", lambda x: {"flops": 10.0})
         def op(x):
             return x * 2
 
@@ -78,6 +79,36 @@ def test_daemon_traces_env_api_gc_and_kernels(tmp_path):
         assert dumps_count == 1
     finally:
         del os.environ["FLARE_TRACED_PYTHON_API"]
+
+
+def test_span_is_a_flare_span_and_a_profiler_annotation(tmp_path):
+    """``span`` records the block as a span of the step and opens a
+    profiler annotation of the same name; the block's own time is not
+    Flare's self time."""
+    import jax
+    d = TracingDaemon(DaemonConfig(rank=0, drain_interval=0.01,
+                                   hang_timeout=1e9))
+    got = []
+    d.add_sink(got.extend)
+    d.attach()
+    d.step_begin(3)
+    jax.profiler.start_trace(str(tmp_path))
+    with d.span(EventKind.PY_API, "phase.x", tokens=8) as s:
+        time.sleep(0.01)
+    jax.profiler.stop_trace()
+    d.step_end()
+    d.detach()
+    ev, = [e for e in got if e.name == "phase.x"]
+    assert (ev.kind, ev.step, ev.meta["tokens"]) == (EventKind.PY_API, 3, 8)
+    assert (ev.start_ts, ev.end_ts) == (s.t0, s.t1)
+    assert s.t1 - s.t0 >= 0.01
+    step, = [e for e in got if e.kind == EventKind.STEP]
+    assert 0 < step.meta["flare_self_ns"] == d.self_ns < 1e7
+    trace, = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(trace))
+    assert "phase.x" in {e.name for p in data.planes
+                         if p.name.startswith("/host:")
+                         for line in p.lines for e in line.events}
 
 
 def test_daemon_counts_failing_sinks():
