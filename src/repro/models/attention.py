@@ -14,6 +14,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import Constrain, apply_rope, normal_init, null_constrain
 
@@ -98,21 +99,60 @@ def direct_attention(q, k, v, causal=True, q_offset=0):
 # --------------------------------------------------------------------------- #
 # Chunked (flash-style) attention — the XLA train/prefill workhorse
 # --------------------------------------------------------------------------- #
-def _chunk_scan(q, k, v, causal, qpos, kv_chunk, return_stats=False):
-    """Online-softmax scan over KV chunks for one q-block.
+def _chunk_sizes(S, T, q_chunk, kv_chunk):
+    """The q block and KV chunk the flash path runs: one block (or chunk)
+    over the whole length wherever the size does not divide it."""
+    q_chunk = min(q_chunk, S)
+    kv_chunk = min(kv_chunk, T)
+    return (S if S % q_chunk else q_chunk), (T if T % kv_chunk else kv_chunk)
 
-    q: [B,Sq,KV,G,hd]; qpos: f32 [Sq] global row positions (an ARRAY so it
-    stays valid when traced, e.g. under shard_map context parallelism)."""
+
+def _kv_chunks_visited(last_qpos, kv_chunk, n_chunks):
+    """How many leading KV chunks a causal q block needs: chunk j holds an
+    unmasked key iff its first key, j * kv_chunk, is at or before the
+    block's last row.  Works on numpy and traced positions alike."""
+    return (last_qpos // kv_chunk + 1).astype("int32").clip(0, n_chunks)
+
+
+def attention_kv_blocks(S, T, q_offset=0, q_chunk=1024, kv_chunk=512,
+                        causal=True):
+    """(visited, total) (q block, KV chunk) pairs of one ``chunked_attention``
+    call per batch row: the loop bound's own arithmetic on the host."""
+    q_chunk, kv_chunk = _chunk_sizes(S, T, q_chunk, kv_chunk)
+    nq, nkv = S // q_chunk, T // kv_chunk
+    if not causal:
+        return nq * nkv, nq * nkv
+    last = (np.arange(1, nq + 1) * q_chunk - 1 + q_offset).astype(np.float32)
+    return int(_kv_chunks_visited(last, kv_chunk, nkv).sum()), nq * nkv
+
+
+def _kv_chunks(x, kv_chunk):
+    """[B,T,KV,hd] -> [T/kv_chunk, B, kv_chunk, KV, hd]."""
+    B, T, KV, hd = x.shape
+    return x.reshape(B, T // kv_chunk, kv_chunk, KV, hd).swapaxes(0, 1)
+
+
+def _n_visited(qpos, kv_chunk, n_chunks, causal):
+    if not causal:
+        return n_chunks
+    return _kv_chunks_visited(jnp.max(qpos), kv_chunk, n_chunks)
+
+
+def _chunk_scan(q, kc, vc, causal, qpos):
+    """Online-softmax loop over KV chunks for one q block.
+
+    q: [B,Sq,KV,G,hd]; kc/vc: [n_chunks,B,kv_chunk,KV,hd]; qpos: f32 [Sq]
+    global row positions (an ARRAY so it stays valid when traced, e.g.
+    under shard_map context parallelism).  Causal, the loop stops at the
+    last chunk at or below the diagonal: every later chunk is wholly
+    masked and would add exact zeros."""
     B, Sq, KV, G, hd = q.shape
-    T = k.shape[1]
-    n_chunks = T // kv_chunk
-    kc = k.reshape(B, n_chunks, kv_chunk, KV, hd)
-    vc = v.reshape(B, n_chunks, kv_chunk, KV, hd)
+    n_chunks, _, kv_chunk = kc.shape[:3]
     scale = hd ** -0.5
 
-    def body(carry, inputs):
+    def body(j, carry):
         o, m, l = carry
-        j, kj, vj = inputs
+        kj, vj = kc[j], vc[j]
         s = jnp.einsum("bskgh,btkh->bkgst", q, kj).astype(jnp.float32) * scale
         if causal:
             kpos = (jnp.arange(kv_chunk) + j * kv_chunk).astype(jnp.float32)
@@ -124,33 +164,29 @@ def _chunk_scan(q, k, v, causal, qpos, kv_chunk, return_stats=False):
         l_new = l * alpha + jnp.sum(p, axis=-1)
         pv = jnp.einsum("bkgst,btkh->bkgsh", p.astype(q.dtype), vj)
         o_new = o * alpha[..., None].astype(o.dtype) + pv
-        return (o_new, m_new, l_new), None
+        return o_new, m_new, l_new
 
     o0 = jnp.zeros((B, KV, G, Sq, hd), q.dtype)
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
-    (o, m, l), _ = jax.lax.scan(
-        body, (o0, m0, l0), (jnp.arange(n_chunks), kc.swapaxes(0, 1), vc.swapaxes(0, 1)))
+    o, m, l = jax.lax.fori_loop(0, _n_visited(qpos, kv_chunk, n_chunks, causal),
+                                body, (o0, m0, l0))
     o = o / jnp.maximum(l, 1e-30)[..., None].astype(o.dtype)
     o = o.transpose(0, 3, 1, 2, 4)  # [B,Sq,KV,G,hd]
-    if return_stats:
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))  # [B,KV,G,Sq]
-        return o, lse
-    return o
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))  # [B,KV,G,Sq]
+    return o, lse
 
 
 def _flash_fwd(qg, k, v, qpos, causal, q_chunk, kv_chunk):
     B, S, KV, G, hd = qg.shape
-    nq = max(S // q_chunk, 1)
-    if S % q_chunk:
-        nq, q_chunk = 1, S
+    nq = S // q_chunk
     qs = qg.reshape(B, nq, q_chunk, KV, G, hd).swapaxes(0, 1)
     qps = qpos.reshape(nq, q_chunk)
+    kc, vc = _kv_chunks(k, kv_chunk), _kv_chunks(v, kv_chunk)
 
     def one_q(args):
         qb, qp = args
-        return _chunk_scan(qb, k, v, causal, qp, kv_chunk,
-                           return_stats=True)
+        return _chunk_scan(qb, kc, vc, causal, qp)
 
     o, lse = jax.lax.map(one_q, (qs, qps))
     # o: [nq, B, bq, KV, G, hd]; lse: [nq, B, KV, G, bq]
@@ -159,21 +195,21 @@ def _flash_fwd(qg, k, v, qpos, causal, q_chunk, kv_chunk):
     return o, lse
 
 
-def _flash_bwd_body(q, k, v, o, do, lse, qpos, causal, kv_chunk):
-    """Recompute-based backward for one q block. Shapes:
-    q/o/do [B,bq,KV,G,hd]; lse [B,KV,G,bq]; k/v [B,T,KV,hd]; qpos [bq]."""
+def _flash_bwd_body(q, kc, vc, o, do, lse, qpos, causal, dk, dv):
+    """Recompute-based backward for one q block.  Shapes: q/o/do
+    [B,bq,KV,G,hd]; lse [B,KV,G,bq]; kc/vc [n_chunks,B,kv_chunk,KV,hd];
+    qpos [bq]; dk/dv float32 running sums over the q blocks, shaped as kc.
+    Visits the forward's chunks; the skipped ones add nothing to dk/dv."""
     B, bq, KV, G, hd = q.shape
-    T = k.shape[1]
+    n_chunks, _, kv_chunk = kc.shape[:3]
     scale = hd ** -0.5
-    nkv = T // kv_chunk
-    kc = k.reshape(B, nkv, kv_chunk, KV, hd).swapaxes(0, 1)
-    vc = v.reshape(B, nkv, kv_chunk, KV, hd).swapaxes(0, 1)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)  # [B,bq,KV,G]
     delta = delta.transpose(0, 2, 3, 1)  # [B,KV,G,bq]
 
-    def body(dq, xs):
-        j, kj, vj = xs
+    def body(j, carry):
+        dq, dk, dv = carry
+        kj, vj = kc[j], vc[j]
         s = jnp.einsum("bskgh,btkh->bkgst", q, kj).astype(jnp.float32) * scale
         if causal:
             kpos = (jnp.arange(kv_chunk) + j * kv_chunk).astype(jnp.float32)
@@ -184,15 +220,16 @@ def _flash_bwd_body(q, k, v, o, do, lse, qpos, causal, kv_chunk):
                         do, vj).astype(jnp.float32)
         ds = p * (dp - delta[..., None]) * scale
         dq = dq + jnp.einsum("bkgst,btkh->bskgh", ds.astype(q.dtype), kj)
-        dkj = jnp.einsum("bkgst,bskgh->btkh", ds.astype(q.dtype), q)
-        dvj = jnp.einsum("bkgst,bskgh->btkh", p.astype(q.dtype), do)
-        return dq, (dkj, dvj)
+        dkj = jnp.einsum("bkgst,bskgh->btkh", ds.astype(q.dtype), q,
+                         preferred_element_type=jnp.float32)
+        dvj = jnp.einsum("bkgst,bskgh->btkh", p.astype(q.dtype), do,
+                         preferred_element_type=jnp.float32)
+        dk = jax.lax.dynamic_update_index_in_dim(dk, dk[j] + dkj, j, 0)
+        dv = jax.lax.dynamic_update_index_in_dim(dv, dv[j] + dvj, j, 0)
+        return dq, dk, dv
 
-    dq0 = jnp.zeros_like(q)
-    dq, (dk_c, dv_c) = jax.lax.scan(body, dq0, (jnp.arange(nkv), kc, vc))
-    dk = dk_c.swapaxes(0, 1).reshape(B, T, KV, hd)
-    dv = dv_c.swapaxes(0, 1).reshape(B, T, KV, hd)
-    return dq, dk, dv
+    return jax.lax.fori_loop(0, _n_visited(qpos, kv_chunk, n_chunks, causal),
+                             body, (jnp.zeros_like(q), dk, dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -209,24 +246,28 @@ def _flash_attention_xla_fwd(q, k, v, qpos, causal, q_chunk, kv_chunk):
 def _flash_attention_xla_bwd(causal, q_chunk, kv_chunk, res, do_):
     q, k, v, qpos, o, lse = res  # q/o/do_ [B,S,KV,G,hd]; lse [B,KV,G,S]
     B, S, KV, G, hd = q.shape
-    nq = max(S // q_chunk, 1)
-    if S % q_chunk:
-        nq = 1
-    bq = S // nq
-    qs = q.reshape(B, nq, bq, KV, G, hd).swapaxes(0, 1)
-    os_ = o.reshape(B, nq, bq, KV, G, hd).swapaxes(0, 1)
-    dos = do_.reshape(B, nq, bq, KV, G, hd).swapaxes(0, 1)
-    lses = lse.reshape(B, KV, G, nq, bq).transpose(3, 0, 1, 2, 4)
-    qps = qpos.reshape(nq, bq)
+    T = k.shape[1]
+    nq = S // q_chunk
 
-    def one_q(args):
+    def blocks(x):
+        return x.reshape(B, nq, q_chunk, KV, G, hd).swapaxes(0, 1)
+
+    lses = lse.reshape(B, KV, G, nq, q_chunk).transpose(3, 0, 1, 2, 4)
+    qps = qpos.reshape(nq, q_chunk)
+    kc, vc = _kv_chunks(k, kv_chunk), _kv_chunks(v, kv_chunk)
+
+    def one_q(acc, args):
         qb, ob, dob, lseb, qp = args
-        return _flash_bwd_body(qb, k, v, ob, dob, lseb, qp, causal, kv_chunk)
+        dq, dk, dv = _flash_bwd_body(qb, kc, vc, ob, dob, lseb, qp, causal,
+                                     *acc)
+        return (dk, dv), dq
 
-    dq, dk, dv = jax.lax.map(one_q, (qs, os_, dos, lses, qps))
+    zeros = jnp.zeros(kc.shape, jnp.float32)
+    (dk, dv), dq = jax.lax.scan(
+        one_q, (zeros, zeros), (blocks(q), blocks(o), blocks(do_), lses, qps))
     dq = dq.swapaxes(0, 1).reshape(B, S, KV, G, hd)
-    dk = jnp.sum(dk, axis=0)
-    dv = jnp.sum(dv, axis=0)
+    dk = dk.swapaxes(0, 1).reshape(B, T, KV, hd).astype(k.dtype)
+    dv = dv.swapaxes(0, 1).reshape(B, T, KV, hd).astype(v.dtype)
     return dq, dk, dv, jnp.zeros_like(qpos)
 
 
@@ -239,15 +280,15 @@ def chunked_attention(q, k, v, causal=True, q_offset=0,
 
     Residuals are only (q, k, v, o, lse) — scores are recomputed per chunk
     in the VJP, so train-time memory is O(S) not O(S^2) (the XLA analogue
-    of the flash-attention backward).  q_offset may be a traced scalar (context
-    parallelism passes the per-shard row offset)."""
+    of the flash-attention backward).  Causal, each q block visits only
+    the KV chunks at or below the diagonal, forward and backward
+    (``attention_kv_blocks`` counts them).  q_offset may be a traced
+    scalar (context parallelism passes the per-shard row offset): the
+    bound comes from the rows' positions, not from a static offset."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
-    kv_chunk = min(kv_chunk, T)
-    if T % kv_chunk:
-        kv_chunk = T
-    q_chunk = min(q_chunk, S)
+    q_chunk, kv_chunk = _chunk_sizes(S, T, q_chunk, kv_chunk)
     qpos = (jnp.arange(S) + q_offset).astype(jnp.float32)
     og = _flash_attention_xla(q.reshape(B, S, KV, G, hd), k, v, qpos,
                               causal, q_chunk, kv_chunk)
@@ -380,18 +421,25 @@ def decode_attention(q, k_cache, v_cache, pos):
 # --------------------------------------------------------------------------- #
 # Dispatcher
 # --------------------------------------------------------------------------- #
+def attention_path(S, T, impl="auto", causal=True):
+    """The path ``attention`` takes for S query and T key rows: direct,
+    folded or chunked."""
+    if impl == "auto":
+        impl = "direct" if S * T <= 1024 * 1024 else "chunked"
+    if impl == "direct":
+        return "direct"
+    if impl == "folded" and causal and S == T:
+        return "folded"
+    return "chunked"
+
+
 def attention(q, k, v, *, causal=True, q_offset=0, impl="auto", fold_depth=4,
               q_chunk=1024, kv_chunk=512):
     """impl: auto | direct | chunked | folded."""
-    S, T = q.shape[1], k.shape[1]
-    if impl == "auto":
-        if S * T <= 1024 * 1024:
-            impl = "direct"
-        else:
-            impl = "chunked"
-    if impl == "direct":
+    path = attention_path(q.shape[1], k.shape[1], impl, causal)
+    if path == "direct":
         return direct_attention(q, k, v, causal, q_offset)
-    if impl == "folded" and causal and S == T:
+    if path == "folded":
         return folded_causal_attention(q, k, v, fold_depth)
     return chunked_attention(q, k, v, causal, q_offset,
                              q_chunk=q_chunk, kv_chunk=kv_chunk)

@@ -11,6 +11,10 @@ is also a profiler annotation (``TracingDaemon.span``):
 ``dataloader.next_batch``, ``train_step.h2d`` (the batch's copies and the
 fault hook), ``train_step.dispatch``, ``train_step.sync`` (the loss
 fetch) and ``train_step.record`` (history, step end, checkpoint).
+Where the step runs the flash path, the daemon's counters
+``attention.kv_blocks_visited`` and ``attention.kv_blocks_total`` grow each
+step by the (q block, KV chunk) pairs one causal self-attention layer
+visits and holds per batch row (``attention_kv_blocks``).
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ import numpy as np
 from repro.configs import ModelConfig
 from repro.core.events import EventKind
 from repro.data import DataConfig, ShardedLoader
+from repro.models.attention import attention_kv_blocks, attention_path
 from repro.models.layers import Policy
 from repro.models.registry import build_model
 from repro.optim.adamw import (AdamWConfig, adamw_init, adamw_update,
@@ -170,6 +175,16 @@ class Trainer:
             vocab_size=c.model.vocab_size, batch=c.global_batch,
             seq_len=c.seq_len, seed=c.seed, mask_mode=c.mask_mode))
 
+    def kv_blocks(self) -> Optional[tuple[int, int]]:
+        """(visited, total) KV block pairs of one causal self-attention
+        layer per batch row, or None where the step runs no flash path."""
+        m, S = self.model, self.cfg.seq_len
+        if (not hasattr(m, "kv_chunk")
+                or attention_path(S, S, self.cfg.attn_impl) != "chunked"):
+            return None
+        return attention_kv_blocks(S, S, q_chunk=m.q_chunk,
+                                   kv_chunk=m.kv_chunk)
+
     def _vision_stub(self):
         c = self.cfg.model
         if c.family != "vlm":
@@ -189,6 +204,11 @@ class Trainer:
             self.daemon.attach()
         daemon = self.daemon
         span = daemon.span if daemon else lambda *a, **k: _NO_SPAN
+        blocks = self.kv_blocks() if daemon else None
+        if blocks:
+            kv_counters = (
+                daemon.telemetry.counter("attention.kv_blocks_visited"),
+                daemon.telemetry.counter("attention.kv_blocks_total"))
         loader = self._loader()
         if cfg.data_prefetch:
             loader.start()
@@ -227,6 +247,9 @@ class Trainer:
                             EventKind.KERNEL_COMPUTE, "train_step_exec",
                             dispatch.t0, sync.t1, flops=step_flops)
                         daemon.step_end(tokens=tokens_per_step, loss=loss)
+                        if blocks:
+                            for c, n in zip(kv_counters, blocks):
+                                c.inc(n)
                     rec = {"step": step, "loss": loss,
                            "lr": float(metrics["lr"]),
                            "grad_norm": float(metrics["grad_norm"]),

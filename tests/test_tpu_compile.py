@@ -17,6 +17,7 @@ from repro.kernels.fused_norm.ops import fused_residual_rmsnorm
 from repro.kernels.padded_matmul.ops import padded_matmul
 from repro.kernels.ring_reduce.ops import ring_combine
 from repro.kernels.ssd_scan.ops import ssd_scan
+from repro.models.attention import chunked_attention
 from repro.optim.adamw import adamw_init
 from repro.runtime.train import RunConfig, Trainer
 
@@ -74,6 +75,24 @@ def test_qwen2_full_width_train_step_fits_v5e(one_chip):
     compiled = trainer.step_fn.lower(*args).compile()
     total = _total_bytes(compiled.memory_analysis())
     assert 0 < total < V5E_HBM_BYTES, total / 2 ** 30
+
+
+def test_flash_attention_fwd_bwd_fits_v5e_at_16k(one_chip):
+    """The flash path's forward and recompute backward at qwen2-0.5b's
+    heads (14 q, 2 KV, head dim 64) over 1 x 16384 tokens: the long-context
+    cell's attention, which the 8 x 1024 step above (direct attention)
+    never compiles."""
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((1, 16384, 14, 64), bf, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 2, 64), bf, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(chunked_attention(q, k, v).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < V5E_HBM_BYTES, temp / 2 ** 30
 
 
 BF, F32 = jnp.bfloat16, jnp.float32

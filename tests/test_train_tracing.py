@@ -1,6 +1,7 @@
 """The trainer's own tracing: named scopes on the jitted step's device
 work, one Flare span per host phase of a step (each also a profiler
 annotation), and Flare's self time per step."""
+import dataclasses
 import re
 
 import jax
@@ -129,3 +130,30 @@ def test_spans_only_with_flare(flare, tmp_path, monkeypatch):
         assert opened == PHASES * 4
     else:
         assert opened == [] and trainer.daemon is None
+
+
+# (seq_len, attn_impl, q_chunk, kv_chunk) -> per-step (visited, total):
+# the bench cells' block grids (16 x 32 at seq16k, 4 x 8 at seq4k) at a
+# reduced length, and the direct path, which runs no flash blocks.
+KV_BLOCK_CASES = {
+    "seq16k_grid": ((128, "chunked", 8, 4), (272, 512)),
+    "seq4k_grid": ((64, "chunked", 16, 8), (20, 32)),
+    "direct": ((32, "auto", 1024, 512), None),
+}
+
+
+@pytest.mark.parametrize("case", list(KV_BLOCK_CASES))
+def test_daemon_counts_kv_blocks_visited_per_step(case):
+    (seq, impl, q_chunk, kv_chunk), want = KV_BLOCK_CASES[case]
+    run = dataclasses.replace(_run(flare=True, attn_impl=impl), seq_len=seq,
+                              steps=3)
+    trainer = Trainer(run)
+    trainer.model.q_chunk, trainer.model.kv_chunk = q_chunk, kv_chunk
+    trainer.train()
+    assert trainer.kv_blocks() == want
+    counters = trainer.daemon.telemetry.snapshot()["counters"]
+    names = ("attention.kv_blocks_visited", "attention.kv_blocks_total")
+    if want is None:
+        assert not set(names) & set(counters)
+    else:
+        assert [counters[n] for n in names] == [3 * n for n in want]
