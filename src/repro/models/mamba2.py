@@ -4,6 +4,8 @@ Chunked SSD algorithm (arXiv:2405.21060): within-chunk quadratic term +
 inter-chunk state recurrence, both expressed with einsums + one lax.scan so
 the compiled HLO is compact and TPU-friendly.  ``ssd_sequential`` is the
 step-by-step recurrence oracle used by tests and the decode path.
+The scan carries the ``ssd`` named scope (``SSD_SCOPE``) in each HLO op's
+``op_name``.
 """
 from __future__ import annotations
 
@@ -14,11 +16,30 @@ from repro.configs import ModelConfig
 from repro.models.layers import (
     Constrain, gated_rmsnorm, normal_init, null_constrain, rmsnorm_init,
 )
+from repro.runtime.train import SSD_SCOPE
 
 
 # --------------------------------------------------------------------------- #
 # Core SSD math (head-dim P, state N). All fp32 internally.
 # --------------------------------------------------------------------------- #
+def ssd_chunk_len(L: int, chunk: int) -> int:
+    """The chunk ``ssd_chunked`` runs over a sequence of ``L``: ``chunk``,
+    or the whole sequence where it is shorter or ``chunk`` does not
+    divide it."""
+    Q = min(chunk, L)
+    return Q if L % Q == 0 else L
+
+
+def ssd_pairs(L: int, chunk: int) -> tuple[int, int]:
+    """(causal, computed) (t, s) pairs of one sequence's chunks: the pairs
+    with s <= t that the within-chunk term needs, ``chunks x Q(Q+1)/2``,
+    and the ``chunks x Q^2`` that ``ssd_chunked`` forms."""
+    Q = ssd_chunk_len(L, chunk)
+    nc = L // Q
+    return nc * Q * (Q + 1) // 2, nc * Q * Q
+
+
+@jax.named_scope(SSD_SCOPE)
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """Chunked SSD scan.
 
@@ -31,9 +52,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """
     Bsz, L, H, P = x.shape
     N = Bm.shape[-1]
-    Q = min(chunk, L)
-    if L % Q:
-        Q = L
+    Q = ssd_chunk_len(L, chunk)
     nc = L // Q
     f32 = jnp.float32
 
@@ -47,10 +66,13 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
 
     # ---- intra-chunk (quadratic in Q) ---------------------------------- #
     # scores[t,s] = (C_t . B_s) * exp(cum_t - cum_s) * dt_s   for s <= t
+    # Above the diagonal cum_t - cum_s is positive and overflows exp over a
+    # long chunk; masked to -inf there, exp gives 0 forward and backward.
     cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc)  # [B,nc,Q,Q]
-    decay = jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :])  # [B,nc,Q,Q,H]
+    segsum = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,Q,Q,H]
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    scores = cb[..., None] * jnp.where(tri[None, None, :, :, None], decay, 0.0)
+    decay = jnp.exp(jnp.where(tri[None, None, :, :, None], segsum, -jnp.inf))
+    scores = cb[..., None] * decay
     scores = scores * dtc[:, :, None, :, :]  # weight by dt_s
     y_intra = jnp.einsum("bctsh,bcshp->bcthp", scores, xc)
 
@@ -104,10 +126,12 @@ def ssd_sequential(x, dt, A, Bm, Cm, initial_state=None):
 def ssd_decode_step(state, xt, dtt, A, Bt, Ct):
     """One-token recurrence. state [B,H,P,N]; returns (y [B,H,P], state)."""
     f32 = jnp.float32
-    decay = jnp.exp(dtt.astype(f32) * A.astype(f32)[None, :])
-    state = state * decay[:, :, None, None] + jnp.einsum(
-        "bh,bn,bhp->bhpn", dtt.astype(f32), Bt.astype(f32), xt.astype(f32))
-    y = jnp.einsum("bn,bhpn->bhp", Ct.astype(f32), state)
+    with jax.named_scope(SSD_SCOPE):
+        decay = jnp.exp(dtt.astype(f32) * A.astype(f32)[None, :])
+        state = state * decay[:, :, None, None] + jnp.einsum(
+            "bh,bn,bhp->bhpn", dtt.astype(f32), Bt.astype(f32),
+            xt.astype(f32))
+        y = jnp.einsum("bn,bhpn->bhp", Ct.astype(f32), state)
     return y.astype(xt.dtype), state
 
 

@@ -1,4 +1,9 @@
-"""Pure-SSM (Mamba2) language model: embed -> N x (norm + SSD block) -> head."""
+"""Pure-SSM (Mamba2) language model: embed -> N x (norm + SSD block) -> head.
+
+Named scopes on the device work: ``embed`` (the lookup), ``ssm``
+(each layer's norm through the mixer's residual add, with the scan's own
+``ssd`` inside), ``head`` (final norm, head and cross-entropy).
+"""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
@@ -10,6 +15,7 @@ import jax.numpy as jnp
 from repro.configs import ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M
+from repro.runtime.train import EMBED_SCOPE, HEAD_SCOPE, SSM_SCOPE
 
 
 def _stack_init(fn, rng, n, *args):
@@ -59,25 +65,29 @@ class MambaLM:
               q_offset=0):
         cfg = self.cfg
         cd = self.policy.compute_dtype
-        x = L.embed_apply(params["embed"], tokens, cd)
-        x = self.constrain(x, ("batch", "seq", "embed"))
+        with jax.named_scope(EMBED_SCOPE):
+            x = L.embed_apply(params["embed"], tokens, cd)
+            x = self.constrain(x, ("batch", "seq", "embed"))
 
         def body(x, lp):
-            h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-            x = x + M.mamba_apply(lp["mamba"], h, cfg, self.constrain)
+            with jax.named_scope(SSM_SCOPE):
+                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                x = x + M.mamba_apply(lp["mamba"], h, cfg, self.constrain)
             return x, None
 
         body = self._maybe_remat(body)
         x, _ = jax.lax.scan(body, x, params["layers"])
-        logits = self._head(params, x)
-        logits = self.constrain(logits, ("batch", "seq", "vocab"))
+        with jax.named_scope(HEAD_SCOPE):
+            logits = self._head(params, x)
+            logits = self.constrain(logits, ("batch", "seq", "vocab"))
         if collect_kv:
             return logits, {}, jnp.zeros((), jnp.float32)
         return logits, jnp.zeros((), jnp.float32)
 
     def loss(self, params, batch, vision_embeds=None):
         logits, _ = self.apply(params, batch["tokens"])
-        ce = L.cross_entropy(logits, batch["labels"])
+        with jax.named_scope(HEAD_SCOPE):
+            ce = L.cross_entropy(logits, batch["labels"])
         return ce, {"ce": ce}
 
     # ------------------------------------------------------------------ #
@@ -96,16 +106,19 @@ class MambaLM:
     def prefill(self, params, tokens, cache, vision_embeds=None):
         cfg = self.cfg
         cd = self.policy.compute_dtype
-        x = L.embed_apply(params["embed"], tokens, cd)
+        with jax.named_scope(EMBED_SCOPE):
+            x = L.embed_apply(params["embed"], tokens, cd)
 
         def body(x, lp):
-            h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-            out, c = M.mamba_apply(lp["mamba"], h, cfg, self.constrain,
-                                   return_state=True)
-            return x + out, c
+            with jax.named_scope(SSM_SCOPE):
+                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                out, c = M.mamba_apply(lp["mamba"], h, cfg, self.constrain,
+                                       return_state=True)
+                return x + out, c
 
         x, caches = jax.lax.scan(body, x, params["layers"])
-        logits = self._head(params, x)
+        with jax.named_scope(HEAD_SCOPE):
+            logits = self._head(params, x)
         new_cache = {"state": caches["state"],
                      "conv": caches["conv"].astype(cd)}
         return logits[:, -1], new_cache
@@ -113,17 +126,20 @@ class MambaLM:
     def decode_step(self, params, token, cache, pos):
         cfg = self.cfg
         cd = self.policy.compute_dtype
-        x = L.embed_apply(params["embed"], token, cd)
+        with jax.named_scope(EMBED_SCOPE):
+            x = L.embed_apply(params["embed"], token, cd)
 
         def body(x, xs):
             lp, st, cv = xs
-            h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-            out, c = M.mamba_decode_step(lp["mamba"], h,
-                                         {"state": st, "conv": cv},
-                                         cfg, self.constrain)
-            return x + out, (c["state"], c["conv"])
+            with jax.named_scope(SSM_SCOPE):
+                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                out, c = M.mamba_decode_step(lp["mamba"], h,
+                                             {"state": st, "conv": cv},
+                                             cfg, self.constrain)
+                return x + out, (c["state"], c["conv"])
 
         x, (st, cv) = jax.lax.scan(
             body, x, (params["layers"], cache["state"], cache["conv"]))
-        logits = self._head(params, x)
+        with jax.named_scope(HEAD_SCOPE):
+            logits = self._head(params, x)
         return logits[:, 0], {"state": st, "conv": cv}

@@ -18,6 +18,7 @@ from repro.configs import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import mamba2 as M
+from repro.runtime.train import SSM_SCOPE
 
 
 def _stack_init(fn, rng, n):
@@ -110,8 +111,10 @@ class Zamba2LM:
 
         def group(x, gp):
             def inner(x, lp):
-                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-                return x + M.mamba_apply(lp["mamba"], h, cfg, self.constrain), None
+                with jax.named_scope(SSM_SCOPE):
+                    h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                    return x + M.mamba_apply(lp["mamba"], h, cfg,
+                                             self.constrain), None
             x, _ = jax.lax.scan(inner, x, gp)
             x, kv = self._shared_block(sp, x, positions)
             return x, kv
@@ -157,10 +160,11 @@ class Zamba2LM:
 
         def group(x, gp):
             def inner(x, lp):
-                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-                out, c = M.mamba_apply(lp["mamba"], h, cfg, self.constrain,
-                                       return_state=True)
-                return x + out, c
+                with jax.named_scope(SSM_SCOPE):
+                    h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                    out, c = M.mamba_apply(lp["mamba"], h, cfg,
+                                           self.constrain, return_state=True)
+                    return x + out, c
             x, caches = jax.lax.scan(inner, x, gp)
             x, kv = self._shared_block(sp, x, positions)
             return x, (caches, kv)
@@ -189,11 +193,12 @@ class Zamba2LM:
 
             def inner(x, ys):
                 lp, sti, cvi = ys
-                h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
-                out, c = M.mamba_decode_step(
-                    lp["mamba"], h, {"state": sti, "conv": cvi}, cfg,
-                    self.constrain)
-                return x + out, (c["state"], c["conv"])
+                with jax.named_scope(SSM_SCOPE):
+                    h = L.rmsnorm(lp["ln"], x, cfg.norm_eps)
+                    out, c = M.mamba_decode_step(
+                        lp["mamba"], h, {"state": sti, "conv": cvi}, cfg,
+                        self.constrain)
+                    return x + out, (c["state"], c["conv"])
 
             x, (st2, cv2) = jax.lax.scan(inner, x, (gp, st, cv))
             x, (k2, v2) = self._shared_block(sp, x, positions,

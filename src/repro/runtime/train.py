@@ -14,7 +14,10 @@ fetch) and ``train_step.record`` (history, step end, checkpoint).
 Where the step runs the flash path, the daemon's counters
 ``attention.kv_blocks_visited`` and ``attention.kv_blocks_total`` grow each
 step by the (q block, KV chunk) pairs one causal self-attention layer
-visits and holds per batch row (``attention_kv_blocks``).
+visits and holds per batch row (``attention_kv_blocks``).  Where it runs
+the chunked SSD scan, ``ssd.pairs_kept`` and ``ssd.pairs_total`` grow each
+step by the causal (t, s) pairs of one layer's chunks per batch row and the
+pairs the scan forms (``ssd_pairs``).
 """
 from __future__ import annotations
 
@@ -75,6 +78,8 @@ HEAD_SCOPE = "head"            # final norm, head and cross-entropy
 OPTIMIZER_SCOPE = "optimizer"  # adamw_update whole, with the clip
 STEP_SCOPES = (EMBED_SCOPE, ATTENTION_SCOPE, MLP_SCOPE, HEAD_SCOPE,
                OPTIMIZER_SCOPE)
+SSM_SCOPE = "ssm"  # a Mamba2 layer: ln through the mixer's residual add
+SSD_SCOPE = "ssd"  # the chunked SSD scan whole, inside ``ssm``
 
 _NO_SPAN = contextlib.nullcontext()
 
@@ -185,6 +190,15 @@ class Trainer:
         return attention_kv_blocks(S, S, q_chunk=m.q_chunk,
                                    kv_chunk=m.kv_chunk)
 
+    def ssd_pairs(self) -> Optional[tuple[int, int]]:
+        """(kept, total) (t, s) pairs of one layer's SSD chunks per batch
+        row, or None where the model runs no SSD scan."""
+        c = self.cfg.model
+        if c.family not in ("ssm", "hybrid"):
+            return None
+        from repro.models.mamba2 import ssd_pairs   # mamba2 imports this
+        return ssd_pairs(self.cfg.seq_len, c.ssm_chunk)
+
     def _vision_stub(self):
         c = self.cfg.model
         if c.family != "vlm":
@@ -204,11 +218,16 @@ class Trainer:
             self.daemon.attach()
         daemon = self.daemon
         span = daemon.span if daemon else lambda *a, **k: _NO_SPAN
-        blocks = self.kv_blocks() if daemon else None
-        if blocks:
-            kv_counters = (
-                daemon.telemetry.counter("attention.kv_blocks_visited"),
-                daemon.telemetry.counter("attention.kv_blocks_total"))
+        counts = []   # (counter, per-step increment)
+        if daemon:
+            for names, per_step in (
+                    (("attention.kv_blocks_visited",
+                      "attention.kv_blocks_total"), self.kv_blocks()),
+                    (("ssd.pairs_kept", "ssd.pairs_total"),
+                     self.ssd_pairs())):
+                if per_step:
+                    counts += [(daemon.telemetry.counter(n), k)
+                               for n, k in zip(names, per_step)]
         loader = self._loader()
         if cfg.data_prefetch:
             loader.start()
@@ -247,9 +266,8 @@ class Trainer:
                             EventKind.KERNEL_COMPUTE, "train_step_exec",
                             dispatch.t0, sync.t1, flops=step_flops)
                         daemon.step_end(tokens=tokens_per_step, loss=loss)
-                        if blocks:
-                            for c, n in zip(kv_counters, blocks):
-                                c.inc(n)
+                        for c, n in counts:
+                            c.inc(n)
                     rec = {"step": step, "loss": loss,
                            "lr": float(metrics["lr"]),
                            "grad_norm": float(metrics["grad_norm"]),

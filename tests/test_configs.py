@@ -27,7 +27,8 @@ EXPECT = {
     "musicgen-large": dict(num_layers=48, d_model=2048, num_heads=32,
                            num_kv_heads=32, d_ff=8192, vocab_size=2048),
     "mamba2-780m": dict(num_layers=48, d_model=1536, num_heads=0,
-                        d_ff=0, vocab_size=50280, ssm_state=128),
+                        d_ff=0, vocab_size=50288, ssm_state=128,
+                        tie_embeddings=True),
     "llama-3.2-vision-11b": dict(num_layers=40, d_model=4096, num_heads=32,
                                  num_kv_heads=8, d_ff=14336,
                                  vocab_size=128256),

@@ -77,6 +77,24 @@ def test_qwen2_full_width_train_step_fits_v5e(one_chip):
     assert 0 < total < V5E_HBM_BYTES, total / 2 ** 30
 
 
+def test_mamba2_full_width_train_step_fits_v5e(one_chip):
+    """The ``mamba2-780m.seq2k`` cell's step: 2 x 2048 tokens at the
+    published chunk of 256, full remat, float32 params and AdamW state,
+    bf16 compute, tied 50,288-row head.  Its compiler account is 14.66 GiB;
+    a third row of 2048 adds about 1.07e9 bytes and would not fit."""
+    run = RunConfig(model=get_config("mamba2-780m"), global_batch=2,
+                    seq_len=2048, remat="full", flare=False)
+    trainer = Trainer(run)
+    params = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: adamw_init(p, run.opt), params)
+    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+    args = _shapes((params, opt, {"tokens": tok, "labels": tok},
+                    jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+    compiled = trainer.step_fn.lower(*args).compile()
+    total = _total_bytes(compiled.memory_analysis())
+    assert 0 < total < V5E_HBM_BYTES, total / 2 ** 30
+
+
 def test_flash_attention_fwd_bwd_fits_v5e_at_16k(one_chip):
     """The flash path's forward and recompute backward at qwen2-0.5b's
     heads (14 q, 2 KV, head dim 64) over 1 x 16384 tokens: the long-context

@@ -13,15 +13,16 @@ from repro.core import daemon as daemon_mod
 from repro.core.events import EventKind
 from repro.core.metrics import aggregate_step
 from repro.optim.adamw import AdamWConfig, adamw_init
-from repro.runtime.train import STEP_SCOPES, RunConfig, Trainer
+from repro.runtime.train import (SSD_SCOPE, SSM_SCOPE, STEP_SCOPES,
+                                 RunConfig, Trainer)
 from repro.store.fcs import read_fcs
 
 PHASES = ["dataloader.next_batch", "train_step.h2d", "train_step.dispatch",
           "train_step.sync", "train_step.record"]
 
 
-def _run(**kw):
-    return RunConfig(model=get_reduced("qwen2-0.5b"), global_batch=2,
+def _run(arch="qwen2-0.5b", **kw):
+    return RunConfig(model=get_reduced(arch), global_batch=2,
                      seq_len=32, steps=4, warmup_steps=2, peak_lr=1e-3,
                      opt=AdamWConfig(lr=1e-3), **kw)
 
@@ -31,9 +32,32 @@ def _has_scope(path: str, scope: str) -> bool:
     return re.search(rf"(^|[/(]){scope}([)/]|$)", path) is not None
 
 
-@pytest.mark.parametrize("remat", ["full", "none"])
-def test_step_ops_carry_each_scope(remat):
-    run = _run(remat=remat, flare=False)
+def _innermost(path: str, scopes) -> str | None:
+    found = [(m.start(), s) for s in scopes
+             for m in re.finditer(rf"(^|[/(]){s}(?=[)/]|$)", path)]
+    return max(found)[1] if found else None
+
+
+# (arch, remat) -> the scopes its step carries, and those its backward
+# keeps inside the transpose
+SCOPE_CASES = {
+    "full": (("qwen2-0.5b", "full"), STEP_SCOPES,
+             ("attention", "mlp", "head")),
+    "none": (("qwen2-0.5b", "none"), STEP_SCOPES,
+             ("attention", "mlp", "head")),
+    "mamba2-full": (("mamba2-780m", "full"),
+                    ("embed", SSM_SCOPE, SSD_SCOPE, "head", "optimizer"),
+                    (SSM_SCOPE, SSD_SCOPE, "head")),
+    "mamba2-none": (("mamba2-780m", "none"),
+                    ("embed", SSM_SCOPE, SSD_SCOPE, "head", "optimizer"),
+                    (SSM_SCOPE, SSD_SCOPE, "head")),
+}
+
+
+@pytest.mark.parametrize("case", list(SCOPE_CASES))
+def test_step_ops_carry_each_scope(case):
+    (arch, remat), carried, backward = SCOPE_CASES[case]
+    run = _run(arch, remat=remat, flare=False)
     trainer = Trainer(run)
     params = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
     opt = jax.eval_shape(lambda p: adamw_init(p, run.opt), params)
@@ -44,10 +68,21 @@ def test_step_ops_carry_each_scope(remat):
     paths = re.findall(r'op_name="([^"]*)"', text)
     assert set(STEP_SCOPES) == {"embed", "attention", "mlp", "head",
                                 "optimizer"}
-    for scope in STEP_SCOPES:
+    assert (SSM_SCOPE, SSD_SCOPE) == ("ssm", "ssd")
+    for scope in carried:
         assert any(_has_scope(p, scope) for p in paths), scope
+    # every scoped op is under one of the step's own scopes; a fused op's
+    # name joins its sources' paths with ";", the first of them whole
+    every = STEP_SCOPES + (SSM_SCOPE, SSD_SCOPE)
+    whole = [p.split(";")[0] for p in paths]
+    assert {_innermost(p, every) for p in whole} - {None} == set(carried)
+    # the scan is inside its layer: ssd is innermost under ssm
+    for p in whole:
+        if _has_scope(p, SSD_SCOPE):
+            assert _innermost(p, every) == SSD_SCOPE, p
+            assert p.rindex(SSM_SCOPE) < p.rindex(SSD_SCOPE), p
     # the backward of each layer keeps its scope inside the transpose
-    for scope in ("attention", "mlp", "head"):
+    for scope in backward:
         assert any("transpose(" in p and _has_scope(p, scope)
                    for p in paths), scope
 
@@ -153,6 +188,31 @@ def test_daemon_counts_kv_blocks_visited_per_step(case):
     assert trainer.kv_blocks() == want
     counters = trainer.daemon.telemetry.snapshot()["counters"]
     names = ("attention.kv_blocks_visited", "attention.kv_blocks_total")
+    if want is None:
+        assert not set(names) & set(counters)
+    else:
+        assert [counters[n] for n in names] == [3 * n for n in want]
+
+
+# arch -> per-step (kept, total) SSD pairs at 2 x 64 tokens, the REDUCED
+# chunk 16: 4 chunks of 16 x 17 / 2 causal pairs of 16 x 16; a transformer
+# runs no SSD scan.
+SSD_PAIR_CASES = {
+    "mamba2-780m": (4 * 136, 4 * 256),
+    "zamba2-2.7b": (4 * 136, 4 * 256),
+    "qwen2-0.5b": None,
+}
+
+
+@pytest.mark.parametrize("arch", list(SSD_PAIR_CASES))
+def test_daemon_counts_ssd_pairs_per_step(arch):
+    want = SSD_PAIR_CASES[arch]
+    run = dataclasses.replace(_run(arch, flare=True), seq_len=64, steps=3)
+    trainer = Trainer(run)
+    trainer.train()
+    assert trainer.ssd_pairs() == want
+    counters = trainer.daemon.telemetry.snapshot()["counters"]
+    names = ("ssd.pairs_kept", "ssd.pairs_total")
     if want is None:
         assert not set(names) & set(counters)
     else:
