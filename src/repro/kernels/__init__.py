@@ -3,13 +3,15 @@
 Kernels (each: kernel.py = pl.pallas_call + BlockSpec, ops.py = jit'd
 wrapper, ref.py = pure-jnp oracle):
   padded_matmul    — Case-2: MXU-alignment padding inside the tile
-  ssd_scan         — Mamba2 chunked state-space scan
+  ssd_scan         — Mamba2 chunked state-space scan, forward and backward
   fused_norm       — residual+RMSNorm fusion (Table-5 minority kernels)
   ring_reduce      — ring-combine step with progress export (intra-kernel
                      inspecting seam)
 
-No model path calls them yet; ``tests/test_tpu_compile.py`` holds each to
-the v5e compiler at real widths.  ``interpret_default()`` is True on the
+Only ``ssd_scan`` is on a model path: ``models/mamba2.ssd_chunked`` calls
+its kernel function on the TPU where ``ssd_path`` says the shapes tile.
+``tests/test_tpu_compile.py`` holds each kernel to the v5e compiler at
+real widths.  ``interpret_default()`` is True on the
 CPU backend, where the tests validate them, and any backend other than
 CPU or TPU is an error rather than a silent switch to the interpreter.
 Every ops.py entry point self-registers with an attached FLARE daemon —

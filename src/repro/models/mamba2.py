@@ -1,11 +1,12 @@
 """Mamba2 / SSD (state-space duality) blocks.
 
 Chunked SSD algorithm (arXiv:2405.21060): within-chunk quadratic term +
-inter-chunk state recurrence, both expressed with einsums + one lax.scan so
-the compiled HLO is compact and TPU-friendly.  ``ssd_sequential`` is the
-step-by-step recurrence oracle used by tests and the decode path.
-The scan carries the ``ssd`` named scope (``SSD_SCOPE``) in each HLO op's
-``op_name``.
+inter-chunk state recurrence.  Where ``ssd_path`` says so (the TPU, shapes
+that tile) ``ssd_chunked`` runs the Pallas kernels of
+``repro.kernels.ssd_scan``; elsewhere, and as their oracle, einsums + one
+lax.scan.  ``ssd_sequential`` is the step-by-step recurrence oracle used by
+tests and the decode path.  The scan carries the ``ssd`` named scope
+(``SSD_SCOPE``) in each HLO op's ``op_name``.
 """
 from __future__ import annotations
 
@@ -13,6 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ModelConfig
+from repro.kernels import interpret_default
+from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
 from repro.models.layers import (
     Constrain, gated_rmsnorm, normal_init, null_constrain, rmsnorm_init,
 )
@@ -39,6 +42,18 @@ def ssd_pairs(L: int, chunk: int) -> tuple[int, int]:
     return nc * Q * (Q + 1) // 2, nc * Q * Q
 
 
+def ssd_path(L: int, chunk: int, H: int, P: int, N: int,
+             backend: str) -> str:
+    """``"pallas"`` where ``ssd_chunked`` runs the Pallas kernels: on the
+    TPU, where ``chunk`` divides L and every block tiles (8, 128) (the
+    chunk and the state in whole 128s, the heads in lane groups of 128
+    columns, at most 128 heads); ``"jnp"`` elsewhere."""
+    tiles = (chunk % 128 == 0 and N % 128 == 0 and H <= 128
+             and (128 % P == 0 or P % 128 == 0) and (H * P) % 128 == 0)
+    return ("pallas" if backend == "tpu" and L % chunk == 0 and tiles
+            else "jnp")
+
+
 @jax.named_scope(SSD_SCOPE)
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """Chunked SSD scan.
@@ -52,6 +67,10 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, initial_state=None):
     """
     Bsz, L, H, P = x.shape
     N = Bm.shape[-1]
+    if ssd_path(L, chunk, H, P, N, jax.default_backend()) == "pallas":
+        return ssd_scan_kernel(x, dt, A, Bm, Cm, chunk=chunk,
+                               initial_state=initial_state,
+                               interpret=interpret_default())
     Q = ssd_chunk_len(L, chunk)
     nc = L // Q
     f32 = jnp.float32

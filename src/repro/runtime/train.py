@@ -17,7 +17,9 @@ step by the (q block, KV chunk) pairs one causal self-attention layer
 visits and holds per batch row (``attention_kv_blocks``).  Where it runs
 the chunked SSD scan, ``ssd.pairs_kept`` and ``ssd.pairs_total`` grow each
 step by the causal (t, s) pairs of one layer's chunks per batch row and the
-pairs the scan forms (``ssd_pairs``).
+pairs the scan forms (``ssd_pairs``), and ``ssd.kernel_layers`` by the
+Mamba2 layers whose scan runs the Pallas kernels (``ssd_path``), where any
+does.
 """
 from __future__ import annotations
 
@@ -199,6 +201,20 @@ class Trainer:
         from repro.models.mamba2 import ssd_pairs   # mamba2 imports this
         return ssd_pairs(self.cfg.seq_len, c.ssm_chunk)
 
+    def ssd_kernel_layers(self) -> int:
+        """Mamba2 layers whose scan takes the Pallas path each step."""
+        c = self.cfg.model
+        if c.family not in ("ssm", "hybrid"):
+            return 0
+        from repro.models import mamba2   # mamba2 imports this module
+        if mamba2.ssd_path(self.cfg.seq_len, c.ssm_chunk, c.ssm_heads,
+                           c.ssm_head_dim, c.ssm_state,
+                           jax.default_backend()) != "pallas":
+            return 0
+        if c.family == "ssm":
+            return c.num_layers
+        return c.num_layers // c.attn_every * c.attn_every
+
     def _vision_stub(self):
         c = self.cfg.model
         if c.family != "vlm":
@@ -220,11 +236,14 @@ class Trainer:
         span = daemon.span if daemon else lambda *a, **k: _NO_SPAN
         counts = []   # (counter, per-step increment)
         if daemon:
+            kernel_layers = self.ssd_kernel_layers()
             for names, per_step in (
                     (("attention.kv_blocks_visited",
                       "attention.kv_blocks_total"), self.kv_blocks()),
                     (("ssd.pairs_kept", "ssd.pairs_total"),
-                     self.ssd_pairs())):
+                     self.ssd_pairs()),
+                    (("ssd.kernel_layers",),
+                     (kernel_layers,) if kernel_layers else None)):
                 if per_step:
                     counts += [(daemon.telemetry.counter(n), k)
                                for n, k in zip(names, per_step)]

@@ -3,16 +3,26 @@ step-by-step recurrence: forward and every gradient, over two chunks, with
 dt and A at the extremes of the Mamba2 init (dt up to 0.1, |A| up to 16).
 There the within-chunk exponents above the diagonal reach 0.1 x 16 x 255,
 far past float32's ``exp``: the gradient is finite only where they are
-masked before ``exp``.  Also the pair counts the trainer's counters add."""
+masked before ``exp``.  Both paths of ``ssd_chunked``: the jnp body and
+the Pallas kernels (interpreted on the CPU), at the published head dim and
+state.  Also the path's choice, and the pair counts the trainer's counters
+add."""
 import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.models.mamba2 import ssd_chunked, ssd_pairs, ssd_sequential
+from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
+from repro.models.mamba2 import (ssd_chunked, ssd_pairs, ssd_path,
+                                 ssd_sequential)
 
-B, L, H, P, N, CHUNK = 1, 512, 4, 8, 16, 256
+B, L, H, P, N, CHUNK = 1, 512, 4, 64, 128, 256
+SCANS = {
+    "jnp": functools.partial(ssd_chunked, chunk=CHUNK),
+    "pallas": functools.partial(ssd_scan_kernel, chunk=CHUNK,
+                                interpret=True),
+}
 # float32 sums in a different order over 512 steps: the observed worst
 # relative error is 7e-6; 1e-4 is the bound, far below any masking fault,
 # which gives NaN or errors of order one.
@@ -50,9 +60,10 @@ def _close(a, b):
 
 @pytest.mark.parametrize("case", ["dt_max", "dt_loguniform",
                                   "initial_state"])
-def test_ssd_chunked_matches_sequential_at_chunk_256(case):
+@pytest.mark.parametrize("path", list(SCANS))
+def test_ssd_chunked_matches_sequential_at_chunk_256(path, case):
     args, S0 = _inputs(case)
-    chunked = functools.partial(ssd_chunked, chunk=CHUNK)
+    chunked = SCANS[path]
     y, S = chunked(*args, initial_state=S0)
     y_ref, S_ref = ssd_sequential(*args, initial_state=S0)
     assert _close(y, y_ref) < RTOL and _close(S, S_ref) < RTOL
@@ -62,6 +73,21 @@ def test_ssd_chunked_matches_sequential_at_chunk_256(case):
     for name, g, r in zip(("x", "dt", "A", "B", "C"), grads, refs):
         assert bool(jnp.all(jnp.isfinite(g))), name
         assert _close(g, r) < RTOL, (name, _close(g, r))
+    if S0 is not None:   # the initial state's gradient too
+        g = jax.grad(lambda s: _loss(chunked, s)(*args))(S0)
+        r = jax.grad(lambda s: _loss(ssd_sequential, s)(*args))(S0)
+        assert _close(g, r) < RTOL, ("initial_state", _close(g, r))
+
+
+@pytest.mark.parametrize("shape,backend,want", [
+    ((2048, 256, 48, 64, 128), "tpu", "pallas"),   # mamba2-780m.seq2k
+    ((2048, 256, 48, 64, 128), "cpu", "jnp"),
+    ((2000, 256, 48, 64, 128), "tpu", "jnp"),      # 256 does not divide L
+    ((2048, 256, 80, 64, 64), "tpu", "jnp"),       # zamba2-2.7b: N 64
+    ((2048, 16, 8, 16, 16), "tpu", "jnp"),         # a reduced config
+])
+def test_ssd_path_runs_the_kernel_where_the_blocks_tile(shape, backend, want):
+    assert ssd_path(*shape, backend) == want
 
 
 @pytest.mark.parametrize("seq,chunk,want", [

@@ -6,7 +6,10 @@ These cases hold the full-width trainer step and every Pallas kernel to
 it at real widths.  The topology is described only inside a fixture, so
 importing this file never loads the TPU library.
 """
+import importlib
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -77,22 +80,75 @@ def test_qwen2_full_width_train_step_fits_v5e(one_chip):
     assert 0 < total < V5E_HBM_BYTES, total / 2 ** 30
 
 
-def test_mamba2_full_width_train_step_fits_v5e(one_chip):
+@pytest.fixture(scope="module")
+def mamba2_steps(one_chip):
+    """The ``mamba2-780m.seq2k`` cell's step compiled on each SSD path:
+    ``pallas`` as the chip runs it (``ssd_path`` asks the backend, which is
+    the CPU here, so it is told the TPU while the step is traced) and
+    ``jnp``, the scan's einsum body."""
+    out = {}
+    for path in ("pallas", "jnp"):
+        run = RunConfig(model=get_config("mamba2-780m"), global_batch=2,
+                        seq_len=2048, remat="full", flare=False)
+        trainer = Trainer(run)
+        params = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
+        opt = jax.eval_shape(lambda p: adamw_init(p, run.opt), params)
+        tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+        args = _shapes((params, opt, {"tokens": tok, "labels": tok},
+                        jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+        with pytest.MonkeyPatch.context() as mp:
+            if path == "pallas":
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+            lowered = trainer.step_fn.lower(*args)
+        out[path] = lowered.compile()
+    return out
+
+
+def test_mamba2_full_width_train_step_fits_v5e(mamba2_steps):
     """The ``mamba2-780m.seq2k`` cell's step: 2 x 2048 tokens at the
     published chunk of 256, full remat, float32 params and AdamW state,
-    bf16 compute, tied 50,288-row head.  Its compiler account is 14.66 GiB;
-    a third row of 2048 adds about 1.07e9 bytes and would not fit."""
-    run = RunConfig(model=get_config("mamba2-780m"), global_batch=2,
-                    seq_len=2048, remat="full", flare=False)
-    trainer = Trainer(run)
-    params = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
-    opt = jax.eval_shape(lambda p: adamw_init(p, run.opt), params)
-    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
-    args = _shapes((params, opt, {"tokens": tok, "labels": tok},
-                    jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
-    compiled = trainer.step_fn.lower(*args).compile()
-    total = _total_bytes(compiled.memory_analysis())
+    bf16 compute, tied 50,288-row head, the scan on the Pallas kernels.
+    Its compiler account is 14.63 GiB; a third row of 2048 adds about
+    1.07e9 bytes and would not fit."""
+    total = _total_bytes(mamba2_steps["pallas"].memory_analysis())
     assert 0 < total < V5E_HBM_BYTES, total / 2 ** 30
+
+
+def _kernel_calls(compiled) -> list[str]:
+    """The ``op_name`` of each Pallas call in a compiled program."""
+    return [m.group(1) for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
+def test_mamba2_step_runs_the_ssd_kernels_within_the_jnp_account(
+        mamba2_steps):
+    """On the chip the scan is a forward call in the step's forward and,
+    under full remat, a forward and a backward call in its backward; the
+    jnp path has none.  The kernels' step holds no more HBM than the
+    jnp path's."""
+    calls = _kernel_calls(mamba2_steps["pallas"])
+    assert [c.rsplit("/", 2)[-2] for c in calls] == [
+        "ssd_scan_fwd", "ssd_scan_fwd", "ssd_scan_bwd"]
+    assert _kernel_calls(mamba2_steps["jnp"]) == []
+    pallas, jnp_ = (_total_bytes(mamba2_steps[p].memory_analysis())
+                    for p in ("pallas", "jnp"))
+    assert pallas <= jnp_, (pallas, jnp_)
+
+
+def test_mamba2_step_kernels_carry_the_ssd_scope(mamba2_steps, monkeypatch):
+    """The forward and backward calls carry ``ssd`` in their ``op_name``,
+    and the benchmark's reduction files them under ``ssd``: an unscoped
+    backward would leave ``ssd_ms`` short and ``ssd_roofline_pct`` high."""
+    monkeypatch.syspath_prepend(str(Path(__file__).parents[1] / "bench"))
+    scopes = importlib.import_module("scopes")
+    ssm_scopes = importlib.import_module("ssm_scopes")
+    calls = _kernel_calls(mamba2_steps["pallas"])
+    assert {c.rsplit("/", 2)[-2] for c in calls} == {"ssd_scan_fwd",
+                                                     "ssd_scan_bwd"}
+    for call in calls:
+        assert "/ssd/" in call, call
+        assert scopes.scope_of(call, ssm_scopes.SCOPES) == "ssd", call
 
 
 def test_flash_attention_fwd_bwd_fits_v5e_at_16k(one_chip):
@@ -120,11 +176,12 @@ KERNEL_CASES = {
                       {}),
     "fused_norm": (fused_residual_rmsnorm,
                    [((4096, 896), BF), ((4096, 896), BF), ((896,), BF)], {}),
-    # mamba2-780m: d_inner 3072 = 48 heads x 64, state 128, 2k tokens
+    # mamba2-780m.seq2k: 2 x 2048 tokens, d_inner 3072 = 48 heads x 64,
+    # state 128, the published chunk of 256
     "ssd_scan": (ssd_scan,
-                 [((1, 2048, 48, 64), BF), ((1, 2048, 48), F32), ((48,), F32),
-                  ((1, 2048, 128), BF), ((1, 2048, 128), BF)],
-                 {"chunk": 128}),
+                 [((2, 2048, 48, 64), BF), ((2, 2048, 48), F32), ((48,), F32),
+                  ((2, 2048, 128), BF), ((2, 2048, 128), BF)],
+                 {"chunk": 256}),
     # one ring step over a 16 MiB float32 gradient shard
     "ring_combine": (ring_combine, [((1 << 22,), F32), ((1 << 22,), F32)],
                      {"block": 8192}),
@@ -138,3 +195,19 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     compiled = op.__wrapped__.lower(*args, interpret=False,
                                     **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_backward_compiles_for_v5e(one_chip):
+    """``jax.grad`` through the SSD scan at the cell's widths: the forward
+    that saves the chunk-start states, and the backward kernel."""
+    _, specs, static = KERNEL_CASES["ssd_scan"]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+
+    def loss(*a):
+        y = ssd_scan.__wrapped__(*a, interpret=False, **static)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 2

@@ -217,3 +217,32 @@ def test_daemon_counts_ssd_pairs_per_step(arch):
         assert not set(names) & set(counters)
     else:
         assert [counters[n] for n in names] == [3 * n for n in want]
+
+
+# arch, whether ssd_path is steered to the kernel -> per-step
+# ssd.kernel_layers (None: no counter).  On the CPU the path is jnp, so the
+# steered cases run the Pallas kernels interpreted; a transformer has no
+# SSD scan whatever the path.
+KERNEL_LAYER_CASES = {
+    "mamba2-kernel": ("mamba2-780m", True, 3),     # REDUCED: 3 layers
+    "mamba2-jnp": ("mamba2-780m", False, None),
+    "qwen2-kernel": ("qwen2-0.5b", True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_LAYER_CASES))
+def test_daemon_counts_ssd_kernel_layers_per_step(case, monkeypatch):
+    from repro.models import mamba2
+    arch, steer, want = KERNEL_LAYER_CASES[case]
+    if steer:
+        monkeypatch.setattr(mamba2, "ssd_path", lambda *a: "pallas")
+    run = dataclasses.replace(_run(arch, flare=True), seq_len=64, steps=3)
+    trainer = Trainer(run)
+    history = trainer.train()
+    assert trainer.ssd_kernel_layers() == (want or 0)
+    assert all(jnp.isfinite(h["loss"]) for h in history)
+    counters = trainer.daemon.telemetry.snapshot()["counters"]
+    if want is None:
+        assert "ssd.kernel_layers" not in counters
+    else:
+        assert counters["ssd.kernel_layers"] == 3 * want
