@@ -88,17 +88,17 @@ def _compile_summary(events) -> dict:
 
 
 def _f32_reference_loss(model_cfg, params, batch) -> float:
-    """Mean step-0 loss in float32 at highest matmul precision, through
-    plain (direct) attention, one sequence at a time so the float32
-    logits of a 152k vocabulary stay one row's worth."""
+    """Mean step-0 loss in float32 at highest matmul precision, one
+    sequence at a time so the float32 logits of a 152k vocabulary stay one
+    row's worth.  At the smoke's 1024 tokens attention takes the direct
+    path (``attention_path``)."""
     import jax
     import jax.numpy as jnp
 
     from repro.models.layers import Policy
     from repro.models.registry import build_model
 
-    ref = build_model(model_cfg, policy=Policy(jnp.float32, jnp.float32),
-                      attn_impl="direct")
+    ref = build_model(model_cfg, policy=Policy(jnp.float32, jnp.float32))
 
     def mean_loss(p, tokens, labels):
         def row(tl):

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.runtime.train import SSD_SCOPE
+from repro.scopes import SSD_SCOPE
 
 F32 = jnp.float32
 LANES = 128

@@ -46,14 +46,12 @@ class DryrunPolicy:
     opt_dtype: str
     microbatches: int
     remat: str
-    attn_impl: str = "chunked"
     fsdp: bool = False               # shard params over data axes too
     sequence_parallel: bool = True   # SP for the residual stream (train)
     sp_prefill: bool = False         # context-parallel prefill (perf knob)
     q_chunk: int = 1024
     kv_chunk: int = 512
     grad_accum_dtype: str = "float32"
-    fold_depth: int = 4
 
     def policy(self) -> Policy:
         return Policy(jnp.dtype(self.param_dtype), jnp.bfloat16)
@@ -140,8 +138,7 @@ def build_cell(arch: str, shape_name: str, mesh, overrides=None):
         (bool(pol.sp_prefill) and shape.kind == "prefill")
     rules = MeshRules(mesh, sequence_parallel=sp)
     model = build_model(cfg, policy=pol.policy(), constrain=rules, mesh=mesh,
-                        attn_impl=pol.attn_impl, remat=pol.remat,
-                        fold_depth=pol.fold_depth)
+                        remat=pol.remat)
     if hasattr(model, "q_chunk"):
         model.q_chunk = pol.q_chunk
         model.kv_chunk = pol.kv_chunk
@@ -176,7 +173,6 @@ def build_cell(arch: str, shape_name: str, mesh, overrides=None):
                         num_microbatches=pol.microbatches,
                         opt=AdamWConfig(state_dtype=pol.opt_dtype),
                         param_dtype=pol.param_dtype, remat=pol.remat,
-                        attn_impl=pol.attn_impl,
                         grad_accum_dtype=pol.grad_accum_dtype)
         step_fn = make_train_step(model, run, mesh=mesh)
         oshapes = jax.eval_shape(
@@ -361,7 +357,7 @@ def main():
                     help="every assigned cell on this mesh (in-process)")
     ap.add_argument("--out", default="dryrun_out")
     ap.add_argument("--override", default="",
-                    help="k=v,k=v policy overrides (e.g. attn_impl=folded)")
+                    help="k=v,k=v policy overrides (e.g. remat=full)")
     ap.add_argument("--tag", default="", help="suffix for output json")
     args = ap.parse_args()
 

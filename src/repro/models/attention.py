@@ -1,16 +1,16 @@
-"""GQA attention: direct, chunked (flash-style in XLA), folded-causal, decode.
+"""GQA attention: three paths, direct, flash and decode.
 
 Layouts: q [B,S,H,hd], k/v [B,T,KV,hd].  GQA groups G = H // KV.
-``chunked_attention`` is the memory-bounded train/prefill path (online
-softmax over KV chunks, optional Q chunking).  ``folded_causal_attention``
-is the beyond-paper FLOP-reduction path (recursive causality folding: the
-upper-triangular blocks are never materialized, cutting HLO FLOPs toward the
-causal-optimal S^2/2).  ``decode_attention`` is the single-token path.
+``attention`` picks between the first two from the shapes alone
+(``attention_path``): ``direct_attention`` materializes the scores, for
+small shapes and as the oracle; ``chunked_attention`` is the
+memory-bounded flash path for train and prefill (online softmax over KV
+chunks, Q in blocks, causal blocks above the diagonal skipped).
+``decode_attention`` is the single-token path against a KV cache.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -142,10 +142,10 @@ def _chunk_scan(q, kc, vc, causal, qpos):
     """Online-softmax loop over KV chunks for one q block.
 
     q: [B,Sq,KV,G,hd]; kc/vc: [n_chunks,B,kv_chunk,KV,hd]; qpos: f32 [Sq]
-    global row positions (an ARRAY so it stays valid when traced, e.g.
-    under shard_map context parallelism).  Causal, the loop stops at the
-    last chunk at or below the diagonal: every later chunk is wholly
-    masked and would add exact zeros."""
+    global row positions (an ARRAY so it stays valid where the offset is
+    traced).  Causal, the loop stops at the last chunk at or below the
+    diagonal: every later chunk is wholly masked and would add exact
+    zeros."""
     B, Sq, KV, G, hd = q.shape
     n_chunks, _, kv_chunk = kc.shape[:3]
     scale = hd ** -0.5
@@ -283,8 +283,8 @@ def chunked_attention(q, k, v, causal=True, q_offset=0,
     of the flash-attention backward).  Causal, each q block visits only
     the KV chunks at or below the diagonal, forward and backward
     (``attention_kv_blocks`` counts them).  q_offset may be a traced
-    scalar (context parallelism passes the per-shard row offset): the
-    bound comes from the rows' positions, not from a static offset."""
+    scalar: the bound comes from the rows' positions, not from a static
+    offset."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -293,111 +293,6 @@ def chunked_attention(q, k, v, causal=True, q_offset=0,
     og = _flash_attention_xla(q.reshape(B, S, KV, G, hd), k, v, qpos,
                               causal, q_chunk, kv_chunk)
     return og.reshape(B, S, H, hd)
-
-
-# --------------------------------------------------------------------------- #
-# Folded-causal attention (beyond-paper perf path)
-# --------------------------------------------------------------------------- #
-# Causal attention over S splits as:
-#   Q_lo  ->  causal(K_lo)                       (recurse)
-#   Q_hi  ->  full(K_lo)  merged with  causal(K_hi)  (recurse)
-# Each fold level removes the strictly-upper quadrant from the compiled HLO,
-# converging to the causal-optimal S^2/2 FLOPs with `depth` levels.
-def _merge_partials(o1, m1, l1, o2, m2, l2):
-    m = jnp.maximum(m1, m2)
-    a1 = jnp.exp(m1 - m)
-    a2 = jnp.exp(m2 - m)
-    l = l1 * a1 + l2 * a2
-    o = o1 * a1[..., None].astype(o1.dtype) + o2 * a2[..., None].astype(o2.dtype)
-    return o, m, l
-
-
-def _full_partial(q, k, v):
-    """Unmasked attention partials. q [B,S,KV,G,hd] -> (o, m, l) unnormalized."""
-    hd = q.shape[-1]
-    s = jnp.einsum("bskgh,btkh->bkgst", q, k).astype(jnp.float32) * (hd ** -0.5)
-    m = jnp.max(s, axis=-1)
-    p = jnp.exp(s - m[..., None])
-    l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("bkgst,btkh->bkgsh", p.astype(q.dtype), v)
-    return o, m, l
-
-
-def _causal_partial(q, k, v, depth):
-    B, S, KV, G, hd = q.shape
-    if depth <= 0 or S % 2 or S < 256:
-        s = jnp.einsum("bskgh,btkh->bkgst", q, k).astype(jnp.float32) * (hd ** -0.5)
-        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-        m = jnp.max(s, axis=-1)
-        p = jnp.exp(s - m[..., None])
-        l = jnp.sum(p, axis=-1)
-        o = jnp.einsum("bkgst,btkh->bkgsh", p.astype(q.dtype), v)
-        return o, m, l
-    h = S // 2
-    q_lo, q_hi = q[:, :h], q[:, h:]
-    k_lo, k_hi = k[:, :h], k[:, h:]
-    v_lo, v_hi = v[:, :h], v[:, h:]
-    o_lo, m_lo, l_lo = _causal_partial(q_lo, k_lo, v_lo, depth - 1)
-    o_f, m_f, l_f = _full_partial(q_hi, k_lo, v_lo)
-    o_c, m_c, l_c = _causal_partial(q_hi, k_hi, v_hi, depth - 1)
-    o_hi, m_hi, l_hi = _merge_partials(o_f, m_f, l_f, o_c, m_c, l_c)
-    o = jnp.concatenate([o_lo, o_hi], axis=3)  # seq axis of [B,KV,G,S,hd]
-    m = jnp.concatenate([m_lo, m_hi], axis=3)
-    l = jnp.concatenate([l_lo, l_hi], axis=3)
-    return o, m, l
-
-
-def folded_causal_attention(q, k, v, depth=4):
-    B, S, H, hd = q.shape
-    KV = k.shape[2]
-    qg = q.reshape(B, S, KV, H // KV, hd)
-    o, _, l = _causal_partial(qg, k, v, depth)
-    o = o / jnp.maximum(l, 1e-30)[..., None].astype(o.dtype)
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
-
-
-# --------------------------------------------------------------------------- #
-# Context-parallel attention (shard_map over the model axis)
-# --------------------------------------------------------------------------- #
-def context_parallel_attention(q, k, v, mesh, *, causal=True, q_offset=0,
-                               q_chunk=1024, kv_chunk=512,
-                               model_axis="model"):
-    """Shard q ROWS over the model axis; k/v replicated per shard.
-
-    An lax.map over a seq-sharded block axis SERIALIZES under SPMD (every
-    device executes every block), so context parallelism must be expressed
-    manually: each model shard computes attention for its 1/M of the query
-    rows against the full K/V.  Causality is preserved via per-shard
-    q_offset.  Differentiating through shard_map psums the replicated
-    k/v cotangents automatically.  Scores memory/traffic drop by M — the
-    fix for heads that don't divide the model axis (arctic's 56).
-    """
-    from jax.sharding import PartitionSpec as P
-
-    M = mesh.shape[model_axis]
-    B, S, H, hd = q.shape
-    if S % M or (S // M) % 16:
-        return chunked_attention(q, k, v, causal, q_offset,
-                                 q_chunk=q_chunk, kv_chunk=kv_chunk)
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    bspec = dp if B % max(
-        1, __import__("math").prod(mesh.shape[a] for a in dp)) == 0 else None
-    s_loc = S // M
-
-    def body(qb, kb, vb):
-        m = jax.lax.axis_index(model_axis)
-        off = q_offset + m * s_loc
-        return chunked_attention(qb, kb, vb, causal=causal, q_offset=off,
-                                 q_chunk=min(q_chunk, s_loc),
-                                 kv_chunk=kv_chunk)
-
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(bspec, model_axis), P(bspec), P(bspec)),
-        out_specs=P(bspec, model_axis),
-        check_vma=False,
-    )(q, k, v)
 
 
 # --------------------------------------------------------------------------- #
@@ -421,25 +316,19 @@ def decode_attention(q, k_cache, v_cache, pos):
 # --------------------------------------------------------------------------- #
 # Dispatcher
 # --------------------------------------------------------------------------- #
-def attention_path(S, T, impl="auto", causal=True):
-    """The path ``attention`` takes for S query and T key rows: direct,
-    folded or chunked."""
-    if impl == "auto":
-        impl = "direct" if S * T <= 1024 * 1024 else "chunked"
-    if impl == "direct":
-        return "direct"
-    if impl == "folded" and causal and S == T:
-        return "folded"
-    return "chunked"
+MAX_DIRECT_SCORES = 1024 * 1024   # S x T, above which attention is flash
 
 
-def attention(q, k, v, *, causal=True, q_offset=0, impl="auto", fold_depth=4,
-              q_chunk=1024, kv_chunk=512):
-    """impl: auto | direct | chunked | folded."""
-    path = attention_path(q.shape[1], k.shape[1], impl, causal)
-    if path == "direct":
+def attention_path(S, T):
+    """The path ``attention`` takes for S query and T key rows: direct up
+    to ``MAX_DIRECT_SCORES`` score elements a head, flash above."""
+    return "direct" if S * T <= MAX_DIRECT_SCORES else "flash"
+
+
+def attention(q, k, v, *, causal=True, q_offset=0, q_chunk=1024,
+              kv_chunk=512):
+    """Attention on the path ``attention_path`` picks for the shapes."""
+    if attention_path(q.shape[1], k.shape[1]) == "direct":
         return direct_attention(q, k, v, causal, q_offset)
-    if path == "folded":
-        return folded_causal_attention(q, k, v, fold_depth)
     return chunked_attention(q, k, v, causal, q_offset,
                              q_chunk=q_chunk, kv_chunk=kv_chunk)

@@ -34,6 +34,24 @@ def normal_init(rng, shape, stddev, dtype):
     return (jax.random.normal(rng, shape, jnp.float32) * stddev).astype(dtype)
 
 
+def stack_init(fn, rng, n: int):
+    """``n`` parameter trees of ``fn(key)`` stacked on a leading axis, the
+    layout ``lax.scan`` over layers consumes."""
+    return jax.vmap(fn)(jax.random.split(rng, n))
+
+
+def maybe_remat(fn, remat: str):
+    """``fn`` under the remat policy: ``full`` recomputes its forward in
+    the backward, ``dots`` saves the matmuls with no batch dims, ``none``
+    saves everything."""
+    if remat == "full":
+        return jax.checkpoint(fn)
+    if remat == "dots":
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+    return fn
+
+
 # --------------------------------------------------------------------------- #
 # RMSNorm
 # --------------------------------------------------------------------------- #

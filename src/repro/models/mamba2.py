@@ -19,7 +19,7 @@ from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
 from repro.models.layers import (
     Constrain, gated_rmsnorm, normal_init, null_constrain, rmsnorm_init,
 )
-from repro.runtime.train import SSD_SCOPE
+from repro.scopes import SSD_SCOPE
 
 
 # --------------------------------------------------------------------------- #
@@ -52,6 +52,20 @@ def ssd_path(L: int, chunk: int, H: int, P: int, N: int,
              and (128 % P == 0 or P % 128 == 0) and (H * P) % 128 == 0)
     return ("pallas" if backend == "tpu" and L % chunk == 0 and tiles
             else "jnp")
+
+
+def ssd_step_counters(cfg: ModelConfig, L: int,
+                      layers: int) -> dict[str, int]:
+    """Daemon counters and what one training step of ``layers`` Mamba2
+    layers over sequences of ``L`` adds to each: one layer's (t, s) pairs
+    per batch row, kept and formed (``ssd_pairs``), and the layers whose
+    scan runs the Pallas kernels, where ``ssd_path`` picks them."""
+    kept, total = ssd_pairs(L, cfg.ssm_chunk)
+    counters = {"ssd.pairs_kept": kept, "ssd.pairs_total": total}
+    if ssd_path(L, cfg.ssm_chunk, cfg.ssm_heads, cfg.ssm_head_dim,
+                cfg.ssm_state, jax.default_backend()) == "pallas":
+        counters["ssd.kernel_layers"] = layers
+    return counters
 
 
 @jax.named_scope(SSD_SCOPE)

@@ -11,12 +11,11 @@ from repro.models.layers import Constrain, Policy, null_constrain
 
 def build_model(cfg: ModelConfig, *, policy: Policy | None = None,
                 constrain: Constrain = null_constrain, mesh: Any = None,
-                attn_impl: str = "auto", remat: str = "none",
-                fold_depth: int = 4):
+                remat: str = "none"):
     """Instantiate the right family for a config."""
     policy = policy or Policy()
     kw = dict(cfg=cfg, policy=policy, constrain=constrain, mesh=mesh,
-              attn_impl=attn_impl, remat=remat, fold_depth=fold_depth)
+              remat=remat)
     if cfg.family == "ssm":
         from repro.models.ssm_lm import MambaLM
         return MambaLM(**kw)

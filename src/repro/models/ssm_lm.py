@@ -15,11 +15,7 @@ import jax.numpy as jnp
 from repro.configs import ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M
-from repro.runtime.train import EMBED_SCOPE, HEAD_SCOPE, SSM_SCOPE
-
-
-def _stack_init(fn, rng, n, *args):
-    return jax.vmap(lambda k: fn(k, *args))(jax.random.split(rng, n))
+from repro.scopes import EMBED_SCOPE, HEAD_SCOPE, SSM_SCOPE
 
 
 @dataclass
@@ -28,9 +24,12 @@ class MambaLM:
     policy: L.Policy = field(default_factory=L.Policy)
     constrain: L.Constrain = L.null_constrain
     mesh: Any = None
-    attn_impl: str = "auto"  # unused (attention-free)
     remat: str = "none"
-    fold_depth: int = 4
+
+    def step_counters(self, seq_len: int) -> dict[str, int]:
+        """Daemon counters and their increments a training step: the SSD
+        scan's, over every layer (``mamba2.ssd_step_counters``)."""
+        return M.ssd_step_counters(self.cfg, seq_len, self.cfg.num_layers)
 
     def init(self, rng) -> dict:
         cfg, pd = self.cfg, self.policy.param_dtype
@@ -38,7 +37,7 @@ class MambaLM:
         params = {
             "embed": L.embed_init(ks[0], cfg.vocab_size, cfg.d_model, pd),
             "final_norm": L.rmsnorm_init(cfg.d_model, pd),
-            "layers": _stack_init(
+            "layers": L.stack_init(
                 lambda k: {"ln": L.rmsnorm_init(cfg.d_model, pd),
                            "mamba": M.mamba_init(k, cfg, pd)},
                 ks[1], cfg.num_layers),
@@ -46,14 +45,6 @@ class MambaLM:
         if not cfg.tie_embeddings:
             params["head"] = L.head_init(ks[2], cfg.d_model, cfg.vocab_size, pd)
         return params
-
-    def _maybe_remat(self, fn):
-        if self.remat == "full":
-            return jax.checkpoint(fn)
-        if self.remat == "dots":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-        return fn
 
     def _head(self, params, x):
         x = L.rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
@@ -75,7 +66,7 @@ class MambaLM:
                 x = x + M.mamba_apply(lp["mamba"], h, cfg, self.constrain)
             return x, None
 
-        body = self._maybe_remat(body)
+        body = L.maybe_remat(body, self.remat)
         x, _ = jax.lax.scan(body, x, params["layers"])
         with jax.named_scope(HEAD_SCOPE):
             logits = self._head(params, x)

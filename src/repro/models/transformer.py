@@ -7,9 +7,8 @@ nested dicts; layer params carry a leading stacked dimension consumed by
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -18,12 +17,7 @@ from repro.configs import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import moe as moe_lib
-from repro.runtime.train import (ATTENTION_SCOPE, EMBED_SCOPE, HEAD_SCOPE,
-                                 MLP_SCOPE)
-
-
-def _stack_init(fn, rng, n, *args):
-    return jax.vmap(lambda k: fn(k, *args))(jax.random.split(rng, n))
+from repro.scopes import ATTENTION_SCOPE, EMBED_SCOPE, HEAD_SCOPE, MLP_SCOPE
 
 
 @dataclass
@@ -32,9 +26,7 @@ class TransformerLM:
     policy: L.Policy = field(default_factory=L.Policy)
     constrain: L.Constrain = L.null_constrain
     mesh: Any = None  # for MoE expert sharding
-    attn_impl: str = "auto"  # auto | direct | chunked | folded
     remat: str = "none"  # none | full | dots
-    fold_depth: int = 4
     q_chunk: int = 1024
     kv_chunk: int = 512
 
@@ -51,6 +43,18 @@ class TransformerLM:
     @property
     def n_self(self) -> int:
         return self.cfg.num_layers - self.n_cross
+
+    def step_counters(self, seq_len: int) -> dict[str, int]:
+        """Daemon counters and what one training step over sequences of
+        ``seq_len`` adds to each: the (q block, KV chunk) pairs one causal
+        self-attention layer visits and holds per batch row
+        (``attention_kv_blocks``), where the step runs the flash path."""
+        if attn_lib.attention_path(seq_len, seq_len) != "flash":
+            return {}
+        visited, total = attn_lib.attention_kv_blocks(
+            seq_len, seq_len, q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
+        return {"attention.kv_blocks_visited": visited,
+                "attention.kv_blocks_total": total}
 
     # ------------------------------------------------------------------ #
     # Init
@@ -100,11 +104,11 @@ class TransformerLM:
         if self.n_cross:
             g = self.n_cross
             per = cfg.cross_attn_every
-            params["layers"] = _stack_init(
-                lambda k: _stack_init(self._layer_init, k, per), ks[2], g)
-            params["cross"] = _stack_init(self._cross_layer_init, ks[3], g)
+            params["layers"] = L.stack_init(
+                lambda k: L.stack_init(self._layer_init, k, per), ks[2], g)
+            params["cross"] = L.stack_init(self._cross_layer_init, ks[3], g)
         else:
-            params["layers"] = _stack_init(
+            params["layers"] = L.stack_init(
                 self._layer_init, ks[2], cfg.num_layers)
         return params
 
@@ -120,15 +124,9 @@ class TransformerLM:
                 q, k, v = attn_lib.project_qkv(
                     p["attn"], h, positions=positions,
                     rope_theta=cfg.rope_theta, constrain=self.constrain)
-                if self.attn_impl == "cp" and self.mesh is not None:
-                    o = attn_lib.context_parallel_attention(
-                        q, k, v, self.mesh, causal=True,
-                        q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
-                else:
-                    o = attn_lib.attention(
-                        q, k, v, causal=True, impl=self.attn_impl,
-                        fold_depth=self.fold_depth, q_chunk=self.q_chunk,
-                        kv_chunk=self.kv_chunk)
+                o = attn_lib.attention(q, k, v, causal=True,
+                                       q_chunk=self.q_chunk,
+                                       kv_chunk=self.kv_chunk)
                 new_kv = (k, v)
             else:
                 k_cache, v_cache = cache
@@ -166,9 +164,7 @@ class TransformerLM:
                                            constrain=self.constrain)
             if cache is not None:  # decode: reuse cached cross K/V
                 k, v = cache
-            o = attn_lib.attention(
-                q, k, v, causal=False, impl="direct"
-                if q.shape[1] * k.shape[1] <= 1 << 22 else "chunked")
+            o = attn_lib.attention(q, k, v, causal=False)
             gate = jnp.tanh(p["attn"]["gate"].astype(x.dtype))
             x = x + gate * attn_lib.project_out(p["attn"], o, self.constrain)
         with jax.named_scope(MLP_SCOPE):
@@ -183,14 +179,6 @@ class TransformerLM:
         if self.cfg.tie_embeddings:
             return L.tied_head_apply(params["embed"], x)
         return L.head_apply(params["head"], x)
-
-    def _maybe_remat(self, fn):
-        if self.remat == "full":
-            return jax.checkpoint(fn)
-        if self.remat == "dots":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-        return fn
 
     # ------------------------------------------------------------------ #
     # Forward (train / prefill)
@@ -218,14 +206,14 @@ class TransformerLM:
                 def inner(x, lp):
                     x, kv, aux = self._self_block(lp, x, positions)
                     return x, (kv, aux)
-                inner = self._maybe_remat(inner)
+                inner = L.maybe_remat(inner, self.remat)
                 x, (kvs, auxs) = jax.lax.scan(inner, x, gp["layers"])
                 vkv = jnp.einsum("btd,dm->btm", vis,
                                  gp["cross"]["kv_proj"].astype(cd))
                 x, cross_kv = self._cross_block(gp["cross"], x, vkv)
                 return x, (kvs, cross_kv, jnp.sum(auxs))
 
-            group = self._maybe_remat(group)
+            group = L.maybe_remat(group, self.remat)
             stacked = {"layers": params["layers"], "cross": params["cross"]}
             x, (kvs, cross_kvs, auxs) = jax.lax.scan(group, x, stacked)
             aux_total = jnp.sum(auxs)
@@ -234,7 +222,7 @@ class TransformerLM:
             def body(x, lp):
                 x, kv, aux = self._self_block(lp, x, positions)
                 return x, (kv, aux)
-            body = self._maybe_remat(body)
+            body = L.maybe_remat(body, self.remat)
             x, (kvs, auxs) = jax.lax.scan(body, x, params["layers"])
             aux_total = jnp.sum(auxs)
             kv_out = {"self": kvs}

@@ -18,11 +18,7 @@ from repro.configs import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import mamba2 as M
-from repro.runtime.train import SSM_SCOPE
-
-
-def _stack_init(fn, rng, n):
-    return jax.vmap(fn)(jax.random.split(rng, n))
+from repro.scopes import SSM_SCOPE
 
 
 @dataclass
@@ -31,13 +27,19 @@ class Zamba2LM:
     policy: L.Policy = field(default_factory=L.Policy)
     constrain: L.Constrain = L.null_constrain
     mesh: Any = None
-    attn_impl: str = "auto"
     remat: str = "none"
-    fold_depth: int = 4
 
     @property
     def n_groups(self) -> int:
         return self.cfg.num_layers // self.cfg.attn_every
+
+    def step_counters(self, seq_len: int) -> dict[str, int]:
+        """Daemon counters and their increments a training step: the SSD
+        scan's, over the Mamba2 layers of its groups
+        (``mamba2.ssd_step_counters``).  The shared attention block adds
+        none."""
+        return M.ssd_step_counters(self.cfg, seq_len,
+                                   self.n_groups * self.cfg.attn_every)
 
     def init(self, rng) -> dict:
         cfg, pd = self.cfg, self.policy.param_dtype
@@ -52,8 +54,8 @@ class Zamba2LM:
             "embed": L.embed_init(ks[0], cfg.vocab_size, cfg.d_model, pd),
             "final_norm": L.rmsnorm_init(cfg.d_model, pd),
             "head": L.head_init(ks[1], cfg.d_model, cfg.vocab_size, pd),
-            "layers": _stack_init(
-                lambda k: _stack_init(mamba_layer, k, per), ks[2], g),
+            "layers": L.stack_init(
+                lambda k: L.stack_init(mamba_layer, k, per), ks[2], g),
             "shared_attn": {
                 "ln1": L.rmsnorm_init(cfg.d_model, pd),
                 "ln2": L.rmsnorm_init(cfg.d_model, pd),
@@ -65,14 +67,6 @@ class Zamba2LM:
         }
         return params
 
-    def _maybe_remat(self, fn):
-        if self.remat == "full":
-            return jax.checkpoint(fn)
-        if self.remat == "dots":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-        return fn
-
     def _shared_block(self, sp, x, positions, cache=None, pos=None):
         cfg = self.cfg
         h = L.rmsnorm(sp["ln1"], x, cfg.norm_eps)
@@ -80,8 +74,7 @@ class Zamba2LM:
             sp["attn"], h, positions=positions, rope_theta=cfg.rope_theta,
             constrain=self.constrain)
         if cache is None:
-            o = attn_lib.attention(q, k, v, causal=True, impl=self.attn_impl,
-                                   fold_depth=self.fold_depth)
+            o = attn_lib.attention(q, k, v, causal=True)
             new_kv = (k, v)
         else:
             kc, vc = cache
@@ -119,7 +112,7 @@ class Zamba2LM:
             x, kv = self._shared_block(sp, x, positions)
             return x, kv
 
-        group = self._maybe_remat(group)
+        group = L.maybe_remat(group, self.remat)
         x, kvs = jax.lax.scan(group, x, params["layers"])
         logits = self._head_out(params, x)
         logits = self.constrain(logits, ("batch", "seq", "vocab"))

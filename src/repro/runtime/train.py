@@ -5,42 +5,34 @@ AdamW with compressed state, LR schedule).  ``Trainer`` is the driver: it
 owns the dataloader, attaches the FLARE daemon, emits step/dataloader
 events, checkpoints, and exposes fault hooks for the supervisor.
 
-The step's device work carries the named scopes below in each HLO op's
-``op_name``, and each host phase of ``Trainer.train`` is a Flare span that
-is also a profiler annotation (``TracingDaemon.span``):
+The step's device work carries the named scopes of ``repro.scopes`` in
+each HLO op's ``op_name``, and each host phase of ``Trainer.train`` is a
+Flare span that is also a profiler annotation (``TracingDaemon.span``):
 ``dataloader.next_batch``, ``train_step.h2d`` (the batch's copies and the
 fault hook), ``train_step.dispatch``, ``train_step.sync`` (the loss
-fetch) and ``train_step.record`` (history, step end, checkpoint).
-Where the step runs the flash path, the daemon's counters
-``attention.kv_blocks_visited`` and ``attention.kv_blocks_total`` grow each
-step by the (q block, KV chunk) pairs one causal self-attention layer
-visits and holds per batch row (``attention_kv_blocks``).  Where it runs
-the chunked SSD scan, ``ssd.pairs_kept`` and ``ssd.pairs_total`` grow each
-step by the causal (t, s) pairs of one layer's chunks per batch row and the
-pairs the scan forms (``ssd_pairs``), and ``ssd.kernel_layers`` by the
-Mamba2 layers whose scan runs the Pallas kernels (``ssd_path``), where any
-does.
+fetch) and ``train_step.record`` (history, step end, checkpoint).  Each
+step also adds to the daemon's counters the increments the model reports
+for its sequence length (``step_counters``): the flash path's KV blocks,
+the SSD scan's pairs and kernel layers.
 """
 from __future__ import annotations
 
 import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import ModelConfig
 from repro.core.events import EventKind
 from repro.data import DataConfig, ShardedLoader
-from repro.models.attention import attention_kv_blocks, attention_path
 from repro.models.layers import Policy
-from repro.models.registry import build_model
-from repro.optim.adamw import (AdamWConfig, adamw_init, adamw_update,
-                               opt_state_specs)
+from repro.models.registry import build_model, modality_inputs
+from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.schedule import warmup_cosine
+from repro.scopes import OPTIMIZER_SCOPE
 
 
 @dataclass
@@ -53,7 +45,6 @@ class RunConfig:
     warmup_steps: int = 20
     peak_lr: float = 3e-4
     remat: str = "none"
-    attn_impl: str = "auto"
     grad_accum_dtype: str = "float32"  # float32 | bfloat16 (microbatching)
     opt: AdamWConfig = field(default_factory=AdamWConfig)
     param_dtype: str = "float32"
@@ -69,19 +60,6 @@ class RunConfig:
     def policy(self) -> Policy:
         return Policy(jnp.dtype(self.param_dtype), jnp.dtype(self.compute_dtype))
 
-
-# ``jax.named_scope`` names of the step's layers.  Scopes only set each HLO
-# op's ``op_name`` metadata (``jvp(attention)``, ``transpose(jvp(mlp))``):
-# the compiled program is otherwise the same.
-EMBED_SCOPE = "embed"          # the token lookup
-ATTENTION_SCOPE = "attention"  # ln1 through the output projection's residual
-MLP_SCOPE = "mlp"              # ln2 through the MLP or MoE and its residual
-HEAD_SCOPE = "head"            # final norm, head and cross-entropy
-OPTIMIZER_SCOPE = "optimizer"  # adamw_update whole, with the clip
-STEP_SCOPES = (EMBED_SCOPE, ATTENTION_SCOPE, MLP_SCOPE, HEAD_SCOPE,
-               OPTIMIZER_SCOPE)
-SSM_SCOPE = "ssm"  # a Mamba2 layer: ln through the mixer's residual add
-SSD_SCOPE = "ssd"  # the chunked SSD scan whole, inside ``ssm``
 
 _NO_SPAN = contextlib.nullcontext()
 
@@ -149,7 +127,7 @@ class Trainer:
     def __init__(self, cfg: RunConfig, fault_hook: Optional[Callable] = None):
         self.cfg = cfg
         self.model = build_model(cfg.model, policy=cfg.policy(),
-                                 attn_impl=cfg.attn_impl, remat=cfg.remat)
+                                 remat=cfg.remat)
         self.step_fn = jax.jit(make_train_step(self.model, cfg),
                                donate_argnums=(0, 1))
         self.fault_hook = fault_hook
@@ -182,45 +160,13 @@ class Trainer:
             vocab_size=c.model.vocab_size, batch=c.global_batch,
             seq_len=c.seq_len, seed=c.seed, mask_mode=c.mask_mode))
 
-    def kv_blocks(self) -> Optional[tuple[int, int]]:
-        """(visited, total) KV block pairs of one causal self-attention
-        layer per batch row, or None where the step runs no flash path."""
-        m, S = self.model, self.cfg.seq_len
-        if (not hasattr(m, "kv_chunk")
-                or attention_path(S, S, self.cfg.attn_impl) != "chunked"):
-            return None
-        return attention_kv_blocks(S, S, q_chunk=m.q_chunk,
-                                   kv_chunk=m.kv_chunk)
-
-    def ssd_pairs(self) -> Optional[tuple[int, int]]:
-        """(kept, total) (t, s) pairs of one layer's SSD chunks per batch
-        row, or None where the model runs no SSD scan."""
-        c = self.cfg.model
-        if c.family not in ("ssm", "hybrid"):
-            return None
-        from repro.models.mamba2 import ssd_pairs   # mamba2 imports this
-        return ssd_pairs(self.cfg.seq_len, c.ssm_chunk)
-
-    def ssd_kernel_layers(self) -> int:
-        """Mamba2 layers whose scan takes the Pallas path each step."""
-        c = self.cfg.model
-        if c.family not in ("ssm", "hybrid"):
-            return 0
-        from repro.models import mamba2   # mamba2 imports this module
-        if mamba2.ssd_path(self.cfg.seq_len, c.ssm_chunk, c.ssm_heads,
-                           c.ssm_head_dim, c.ssm_state,
-                           jax.default_backend()) != "pallas":
-            return 0
-        if c.family == "ssm":
-            return c.num_layers
-        return c.num_layers // c.attn_every * c.attn_every
-
-    def _vision_stub(self):
-        c = self.cfg.model
-        if c.family != "vlm":
-            return None
-        return jnp.ones((self.cfg.global_batch, c.vision_tokens, c.vision_d),
-                        jnp.dtype(self.cfg.compute_dtype))
+    def _modality_stubs(self) -> dict:
+        """Stand-ins for the stubbed modality front ends' embeddings, one
+        per input the model takes (``modality_inputs``)."""
+        c = self.cfg
+        return {name: jnp.ones(shape, jnp.dtype(c.compute_dtype))
+                for name, shape in modality_inputs(
+                    c.model, c.global_batch).items()}
 
     # ------------------------------------------------------------------ #
     def train(self, steps: Optional[int] = None) -> list[dict]:
@@ -236,22 +182,13 @@ class Trainer:
         span = daemon.span if daemon else lambda *a, **k: _NO_SPAN
         counts = []   # (counter, per-step increment)
         if daemon:
-            kernel_layers = self.ssd_kernel_layers()
-            for names, per_step in (
-                    (("attention.kv_blocks_visited",
-                      "attention.kv_blocks_total"), self.kv_blocks()),
-                    (("ssd.pairs_kept", "ssd.pairs_total"),
-                     self.ssd_pairs()),
-                    (("ssd.kernel_layers",),
-                     (kernel_layers,) if kernel_layers else None)):
-                if per_step:
-                    counts += [(daemon.telemetry.counter(n), k)
-                               for n, k in zip(names, per_step)]
+            counts = [(daemon.telemetry.counter(name), n) for name, n
+                      in self.model.step_counters(cfg.seq_len).items()]
         loader = self._loader()
         if cfg.data_prefetch:
             loader.start()
         params, opt_state, start = self.restore_or_init()
-        vis = self._vision_stub()
+        stubs = self._modality_stubs()
         tokens_per_step = cfg.global_batch * cfg.seq_len
         step_flops = 6.0 * cfg.model.active_param_count() * tokens_per_step
         try:
@@ -267,9 +204,7 @@ class Trainer:
                     if daemon:
                         daemon.set_stack(["Trainer.train", "train_step"])
                     jb = {"tokens": jnp.asarray(batch["tokens"]),
-                          "labels": jnp.asarray(batch["labels"])}
-                    if vis is not None:
-                        jb["vision_embeds"] = vis
+                          "labels": jnp.asarray(batch["labels"]), **stubs}
                     if self.fault_hook:
                         self.fault_hook(step)
                 with span(EventKind.PY_API, "train_step.dispatch") as dispatch:

@@ -1,13 +1,13 @@
-"""Attention implementation equivalence incl. the folded-causal perf path
-and the flash-style custom VJP."""
+"""Attention paths against direct attention, the flash-style custom VJP,
+and the path each shape takes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import (attention_kv_blocks, chunked_attention,
-                                    decode_attention, direct_attention,
-                                    folded_causal_attention)
+from repro.models.attention import (attention_kv_blocks, attention_path,
+                                    chunked_attention, decode_attention,
+                                    direct_attention)
 
 
 @pytest.fixture
@@ -19,12 +19,19 @@ def qkv(rng):
     return q, k, v
 
 
-def test_folded_equals_direct(qkv):
-    q, k, v = qkv
-    for depth in (1, 2, 3):
-        o = folded_causal_attention(q, k, v, depth=depth)
-        r = direct_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(o, r, rtol=3e-4, atol=3e-4)
+# (S, T) -> path: direct up to 2^20 score elements a head, flash above; a
+# vision cross block's 2048 rows over 1025 image tokens is past the limit.
+PATH_CASES = {
+    "self_at_limit": ((1024, 1024), "direct"),
+    "self_past_limit": ((1024, 1025), "flash"),
+    "cross_past_limit": ((2048, 1025), "flash"),
+}
+
+
+@pytest.mark.parametrize("case", list(PATH_CASES))
+def test_attention_path_by_size(case):
+    (S, T), want = PATH_CASES[case]
+    assert attention_path(S, T) == want
 
 
 def test_chunked_gradients_match_direct(qkv):

@@ -13,8 +13,9 @@ from repro.core import daemon as daemon_mod
 from repro.core.events import EventKind
 from repro.core.metrics import aggregate_step
 from repro.optim.adamw import AdamWConfig, adamw_init
-from repro.runtime.train import (SSD_SCOPE, SSM_SCOPE, STEP_SCOPES,
-                                 RunConfig, Trainer)
+from repro.models import attention
+from repro.runtime.train import RunConfig, Trainer
+from repro.scopes import SSD_SCOPE, SSM_SCOPE, STEP_SCOPES
 from repro.store.fcs import read_fcs
 
 PHASES = ["dataloader.next_batch", "train_step.h2d", "train_step.dispatch",
@@ -167,31 +168,45 @@ def test_spans_only_with_flare(flare, tmp_path, monkeypatch):
         assert opened == [] and trainer.daemon is None
 
 
-# (seq_len, attn_impl, q_chunk, kv_chunk) -> per-step (visited, total):
-# the bench cells' block grids (16 x 32 at seq16k, 4 x 8 at seq4k) at a
-# reduced length, and the direct path, which runs no flash blocks.
+# (seq_len, flash forced, q_chunk, kv_chunk) -> per-step (visited,
+# total): the bench cells' block grids (16 x 32 at seq16k, 4 x 8 at
+# seq4k) at a reduced length, put on the flash path by lowering the
+# direct path's size limit, and the direct path, which runs no flash
+# blocks and reports no counter.
 KV_BLOCK_CASES = {
-    "seq16k_grid": ((128, "chunked", 8, 4), (272, 512)),
-    "seq4k_grid": ((64, "chunked", 16, 8), (20, 32)),
-    "direct": ((32, "auto", 1024, 512), None),
+    "seq16k_grid": ((128, True, 8, 4), (272, 512)),
+    "seq4k_grid": ((64, True, 16, 8), (20, 32)),
+    "direct": ((32, False, 1024, 512), None),
 }
+
+KV_NAMES = ("attention.kv_blocks_visited", "attention.kv_blocks_total")
+SSD_NAMES = ("ssd.pairs_kept", "ssd.pairs_total")
+
+
+def _counted(trainer, names, steps=3):
+    """The model's per-step increments of ``names`` and the daemon's
+    counters after ``steps`` steps, where it reports them (None: where it
+    reports none of them, and the daemon then holds none)."""
+    per_step = trainer.model.step_counters(trainer.cfg.seq_len)
+    counters = trainer.daemon.telemetry.snapshot()["counters"]
+    if not set(names) & set(per_step):
+        assert not set(names) & set(counters)
+        return None
+    assert [counters[n] for n in names] == [steps * per_step[n]
+                                            for n in names]
+    return tuple(per_step[n] for n in names)
 
 
 @pytest.mark.parametrize("case", list(KV_BLOCK_CASES))
-def test_daemon_counts_kv_blocks_visited_per_step(case):
-    (seq, impl, q_chunk, kv_chunk), want = KV_BLOCK_CASES[case]
-    run = dataclasses.replace(_run(flare=True, attn_impl=impl), seq_len=seq,
-                              steps=3)
+def test_daemon_counts_kv_blocks_visited_per_step(case, monkeypatch):
+    (seq, flash, q_chunk, kv_chunk), want = KV_BLOCK_CASES[case]
+    if flash:
+        monkeypatch.setattr(attention, "MAX_DIRECT_SCORES", 0)
+    run = dataclasses.replace(_run(flare=True), seq_len=seq, steps=3)
     trainer = Trainer(run)
     trainer.model.q_chunk, trainer.model.kv_chunk = q_chunk, kv_chunk
     trainer.train()
-    assert trainer.kv_blocks() == want
-    counters = trainer.daemon.telemetry.snapshot()["counters"]
-    names = ("attention.kv_blocks_visited", "attention.kv_blocks_total")
-    if want is None:
-        assert not set(names) & set(counters)
-    else:
-        assert [counters[n] for n in names] == [3 * n for n in want]
+    assert _counted(trainer, KV_NAMES) == want
 
 
 # arch -> per-step (kept, total) SSD pairs at 2 x 64 tokens, the REDUCED
@@ -210,13 +225,7 @@ def test_daemon_counts_ssd_pairs_per_step(arch):
     run = dataclasses.replace(_run(arch, flare=True), seq_len=64, steps=3)
     trainer = Trainer(run)
     trainer.train()
-    assert trainer.ssd_pairs() == want
-    counters = trainer.daemon.telemetry.snapshot()["counters"]
-    names = ("ssd.pairs_kept", "ssd.pairs_total")
-    if want is None:
-        assert not set(names) & set(counters)
-    else:
-        assert [counters[n] for n in names] == [3 * n for n in want]
+    assert _counted(trainer, SSD_NAMES) == want
 
 
 # arch, whether ssd_path is steered to the kernel -> per-step
@@ -239,10 +248,6 @@ def test_daemon_counts_ssd_kernel_layers_per_step(case, monkeypatch):
     run = dataclasses.replace(_run(arch, flare=True), seq_len=64, steps=3)
     trainer = Trainer(run)
     history = trainer.train()
-    assert trainer.ssd_kernel_layers() == (want or 0)
     assert all(jnp.isfinite(h["loss"]) for h in history)
-    counters = trainer.daemon.telemetry.snapshot()["counters"]
-    if want is None:
-        assert "ssd.kernel_layers" not in counters
-    else:
-        assert counters["ssd.kernel_layers"] == 3 * want
+    got = _counted(trainer, ("ssd.kernel_layers",))
+    assert got == (None if want is None else (want,))
