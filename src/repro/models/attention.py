@@ -15,8 +15,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.models.layers import Constrain, apply_rope, normal_init, null_constrain
+from repro.scopes import ATTENTION_LSE, ATTENTION_OUT
 
 NEG_INF = -1e30
 
@@ -232,14 +234,16 @@ def _flash_bwd_body(q, kc, vc, o, do, lse, qpos, causal, dk, dv):
                              body, (jnp.zeros_like(q), dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_attention_xla(q, k, v, qpos, causal, q_chunk, kv_chunk):
-    o, _ = _flash_fwd(q, k, v, qpos, causal, q_chunk, kv_chunk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash_attention_xla(q, k, v, qpos, o, lse, causal, q_chunk, kv_chunk):
+    """``o``, the flash forward's output, with the flash backward: the
+    forward runs outside, so that a remat policy can keep ``o`` and
+    ``lse`` by name."""
     return o
 
 
-def _flash_attention_xla_fwd(q, k, v, qpos, causal, q_chunk, kv_chunk):
-    o, lse = _flash_fwd(q, k, v, qpos, causal, q_chunk, kv_chunk)
+def _flash_attention_xla_fwd(q, k, v, qpos, o, lse, causal, q_chunk,
+                             kv_chunk):
     return o, (q, k, v, qpos, o, lse)
 
 
@@ -268,7 +272,8 @@ def _flash_attention_xla_bwd(causal, q_chunk, kv_chunk, res, do_):
     dq = dq.swapaxes(0, 1).reshape(B, S, KV, G, hd)
     dk = dk.swapaxes(0, 1).reshape(B, T, KV, hd).astype(k.dtype)
     dv = dv.swapaxes(0, 1).reshape(B, T, KV, hd).astype(v.dtype)
-    return dq, dk, dv, jnp.zeros_like(qpos)
+    return (dq, dk, dv, jnp.zeros_like(qpos), jnp.zeros_like(o),
+            jnp.zeros_like(lse))
 
 
 _flash_attention_xla.defvjp(_flash_attention_xla_fwd, _flash_attention_xla_bwd)
@@ -284,14 +289,24 @@ def chunked_attention(q, k, v, causal=True, q_offset=0,
     the KV chunks at or below the diagonal, forward and backward
     (``attention_kv_blocks`` counts them).  q_offset may be a traced
     scalar: the bound comes from the rows' positions, not from a static
-    offset."""
+    offset.
+
+    The forward runs outside the custom VJP, with no gradient of its own,
+    and its ``o`` and ``lse`` carry the names ``ATTENTION_OUT`` and
+    ``ATTENTION_LSE``: a remat policy that saves them (``maybe_remat``'s
+    ``full``) keeps the forward from running again in the backward."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
     q_chunk, kv_chunk = _chunk_sizes(S, T, q_chunk, kv_chunk)
     qpos = (jnp.arange(S) + q_offset).astype(jnp.float32)
-    og = _flash_attention_xla(q.reshape(B, S, KV, G, hd), k, v, qpos,
-                              causal, q_chunk, kv_chunk)
+    qg = q.reshape(B, S, KV, G, hd)
+    o, lse = _flash_fwd(*jax.lax.stop_gradient((qg, k, v)), qpos, causal,
+                        q_chunk, kv_chunk)
+    o = checkpoint_name(o, ATTENTION_OUT)
+    lse = checkpoint_name(lse, ATTENTION_LSE)
+    og = _flash_attention_xla(qg, k, v, qpos, o, lse, causal, q_chunk,
+                              kv_chunk)
     return og.reshape(B, S, H, hd)
 
 

@@ -7,6 +7,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import ATTENTION_LSE, ATTENTION_OUT
+
 Constrain = Callable[[jax.Array, tuple], jax.Array]
 
 
@@ -42,10 +44,13 @@ def stack_init(fn, rng, n: int):
 
 def maybe_remat(fn, remat: str):
     """``fn`` under the remat policy: ``full`` recomputes its forward in
-    the backward, ``dots`` saves the matmuls with no batch dims, ``none``
-    saves everything."""
+    the backward except the flash attention forward's named outputs
+    (``ATTENTION_OUT``, ``ATTENTION_LSE``), ``dots`` saves the matmuls with
+    no batch dims, ``none`` saves everything."""
     if remat == "full":
-        return jax.checkpoint(fn)
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.save_only_these_names(
+                ATTENTION_OUT, ATTENTION_LSE))
     if remat == "dots":
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)

@@ -46,15 +46,20 @@ class TransformerLM:
 
     def step_counters(self, seq_len: int) -> dict[str, int]:
         """Daemon counters and what one training step over sequences of
-        ``seq_len`` adds to each: the (q block, KV chunk) pairs one causal
-        self-attention layer visits and holds per batch row
-        (``attention_kv_blocks``), where the step runs the flash path."""
+        ``seq_len`` adds to each, where the step runs the flash path: the
+        (q block, KV chunk) pairs one causal self-attention layer visits
+        and holds per batch row (``attention_kv_blocks``), and under
+        ``full`` remat the self-attention layers whose flash forward the
+        backward keeps rather than runs again."""
         if attn_lib.attention_path(seq_len, seq_len) != "flash":
             return {}
         visited, total = attn_lib.attention_kv_blocks(
             seq_len, seq_len, q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
-        return {"attention.kv_blocks_visited": visited,
-                "attention.kv_blocks_total": total}
+        counters = {"attention.kv_blocks_visited": visited,
+                    "attention.kv_blocks_total": total}
+        if self.remat == "full":
+            counters["attention.fwd_kept_layers"] = self.n_self
+        return counters
 
     # ------------------------------------------------------------------ #
     # Init

@@ -1,4 +1,5 @@
-"""``jax.named_scope`` names of the training step's layers.
+"""``jax.named_scope`` names of the training step's layers, and the
+``checkpoint_name`` names a remat policy saves.
 
 Scopes only set each HLO op's ``op_name`` metadata (``jvp(attention)``,
 ``transpose(jvp(mlp))``): the compiled program is otherwise the same.  A
@@ -17,3 +18,9 @@ STEP_SCOPES = (EMBED_SCOPE, ATTENTION_SCOPE, MLP_SCOPE, HEAD_SCOPE,
                OPTIMIZER_SCOPE)
 SSM_SCOPE = "ssm"  # a Mamba2 layer: ln through the mixer's residual add
 SSD_SCOPE = "ssd"  # the chunked SSD scan whole, inside ``ssm``
+
+# ``checkpoint_name`` names of the flash attention forward's outputs, which
+# the ``full`` remat policy keeps so the forward is not run again in the
+# backward (``models/attention.py chunked_attention``, ``layers.maybe_remat``)
+ATTENTION_OUT = "attention_out"  # o [B,S,KV,G,hd], the compute dtype
+ATTENTION_LSE = "attention_lse"  # log-sum-exp [B,KV,G,S], float32

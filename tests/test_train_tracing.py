@@ -209,6 +209,32 @@ def test_daemon_counts_kv_blocks_visited_per_step(case, monkeypatch):
     assert _counted(trainer, KV_NAMES) == want
 
 
+# (arch, remat, flash forced) -> per-step attention.fwd_kept_layers: the
+# REDUCED qwen2's 2 layers keep their flash forward under full remat; other
+# policies, the direct path and the SSM models report none.
+FWD_KEPT_CASES = {
+    "flash-full": (("qwen2-0.5b", "full", True), (2,)),
+    "flash-none": (("qwen2-0.5b", "none", True), None),
+    "flash-dots": (("qwen2-0.5b", "dots", True), None),
+    "direct-full": (("qwen2-0.5b", "full", False), None),
+    "mamba2-full": (("mamba2-780m", "full", True), None),
+    "zamba2-full": (("zamba2-2.7b", "full", True), None),
+}
+
+
+@pytest.mark.parametrize("case", list(FWD_KEPT_CASES))
+def test_daemon_counts_flash_forwards_kept_per_step(case, monkeypatch):
+    (arch, remat, flash), want = FWD_KEPT_CASES[case]
+    if flash:
+        monkeypatch.setattr(attention, "MAX_DIRECT_SCORES", 0)
+    run = dataclasses.replace(_run(arch, flare=True, remat=remat),
+                              seq_len=64, steps=3)
+    trainer = Trainer(run)
+    trainer.model.q_chunk, trainer.model.kv_chunk = 16, 8
+    trainer.train()
+    assert _counted(trainer, ("attention.fwd_kept_layers",)) == want
+
+
 # arch -> per-step (kept, total) SSD pairs at 2 x 64 tokens, the REDUCED
 # chunk 16: 4 chunks of 16 x 17 / 2 causal pairs of 16 x 16; a transformer
 # runs no SSD scan.
